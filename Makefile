@@ -6,7 +6,7 @@
 
 PY ?= python
 
-.PHONY: test test-fast protos native bench bench-tpu sweeps dryrun lint ci
+.PHONY: test test-fast protos native bench smoke sweeps dryrun lint ci
 
 test:          ## full hermetic suite (CPU, virtual 8-device mesh)
 	$(PY) -m pytest tests/ -q
@@ -38,7 +38,7 @@ test-fast:     ## ~8 min hermetic signal incl. core invariants + tiny Pallas
 	    tests/test_federation.py tests/test_fleet_telemetry.py \
 	    tests/test_single_device_donation.py \
 	    tests/test_sparse_degraded.py \
-	    tests/test_pallas_fast.py tests/test_bench_ladder.py -q
+	    tests/test_pallas_fast.py -q
 
 protos:        ## regenerate *_pb2.py from protos/*.proto
 	cd protos && ./generate.sh
@@ -46,11 +46,11 @@ protos:        ## regenerate *_pb2.py from protos/*.proto
 native:        ## build the C++ oracle kernels (ctypes-loaded)
 	cd native && ./build.sh
 
-bench:         ## headline benchmark (real TPU; emits one JSON line)
+bench:         ## headline benchmark (TPU only; emits one JSON line)
 	$(PY) bench.py
 
-bench-tpu:     ## full hardware capture into benchmarks/results/
-	bash benchmarks/capture_tpu.sh
+smoke:         ## served-path bring-up check on one chip
+	$(PY) chip_smoke.py
 
 sweeps:        ## reference-mirroring benchmark sweeps (small shapes)
 	$(PY) benchmarks/run_benchmarks.py

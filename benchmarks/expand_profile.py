@@ -56,9 +56,11 @@ def main():
     import jax
     import jax.numpy as jnp
 
-    from benchmarks.common import setup_compilation_cache
+    from distributed_point_functions_tpu.serving.service import (
+        install_compile_cache,
+    )
 
-    setup_compilation_cache()
+    install_compile_cache()
     log(f"devices: {jax.devices()}")
 
     from distributed_point_functions_tpu import keys as fk

@@ -28,7 +28,7 @@ Run directly (one JSON report on stdout, also written to
 
 or through the headline harness (one bench-style JSON line)::
 
-    BENCH_HEAVY_HITTERS=1 BENCH_PLATFORM=cpu python bench.py
+    JAX_PLATFORMS=cpu BENCH_HEAVY_HITTERS=1 python bench.py
 
 Environment knobs: HH_BENCH_CLIENTS (default 48), HH_BENCH_DOMAIN_BITS
 ("16"), HH_BENCH_LEVEL_BITS (4), HH_BENCH_THRESHOLDS ("2,4"),

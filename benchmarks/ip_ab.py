@@ -48,9 +48,11 @@ def main():
 
     import jax
 
-    from benchmarks.common import setup_compilation_cache
+    from distributed_point_functions_tpu.serving.service import (
+        install_compile_cache,
+    )
 
-    setup_compilation_cache()
+    install_compile_cache()
 
     from distributed_point_functions_tpu.ops.inner_product import (
         xor_inner_product,

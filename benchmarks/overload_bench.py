@@ -22,7 +22,7 @@ higher`` — to the regression-gate history)::
 
 or through the headline harness (one bench-style JSON line)::
 
-    BENCH_OVERLOAD=1 BENCH_PLATFORM=cpu python bench.py
+    JAX_PLATFORMS=cpu BENCH_OVERLOAD=1 python bench.py
 
 The request pool comes from `benchmarks/workload_gen.py` profiles
 (``--profile`` / OVERLOAD_BENCH_PROFILE). The default ``uniform``

@@ -51,7 +51,7 @@ Run directly (one JSON report on stdout, also written to
 
 or through the headline harness (one bench-style JSON line)::
 
-    BENCH_SERVING=1 BENCH_PLATFORM=cpu python bench.py
+    JAX_PLATFORMS=cpu BENCH_SERVING=1 python bench.py
 
 The bench closes with a **mesh stage**: the same closed-loop point
 served by one logical server spread over a 2-D device mesh

@@ -128,9 +128,11 @@ def read_unique_values_from_file(path: str) -> list:
 
 
 def main():
-    from benchmarks.common import setup_compilation_cache
+    from distributed_point_functions_tpu.serving.service import (
+        install_compile_cache,
+    )
 
-    setup_compilation_cache()
+    install_compile_cache()
     import os
 
     if os.environ.get("JAX_PLATFORMS") == "cpu":

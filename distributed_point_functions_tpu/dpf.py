@@ -258,8 +258,8 @@ def _build_fused_accumulate(plan, vt, blocks_needed):
             break
 
     # The walk below runs inside this program's jit trace, where the
-    # level kernels' on-device self-check cannot run; warm eagerly so the
-    # traced walk serves the verified Pallas kernels.
+    # level kernels' on-device self-check cannot run; run it eagerly
+    # first so a miscompiling kernel raises here.
     from .pir.dense_eval_planes import warm_level_kernels
 
     warm_level_kernels()
@@ -693,10 +693,10 @@ def _expand_levels_planes_fn(num_levels: int, level_kernel: bool = False,
 def _expand_levels_fn(num_levels: int, hash_leaves: bool = False):
     """Dispatch the fused expansion program: `DPF_TPU_EXPAND_LEVELS` =
     `limb` | `planes` | `auto` (default: planes on TPU, limb elsewhere).
-    On TPU the plane levels run the fused Pallas kernel
-    (`DPF_TPU_LEVEL_KERNEL`), falling back to the XLA level on compile
-    failure. `hash_leaves` fuses the leaf value MMO hash into the same
-    program (single-block value types)."""
+    On TPU the plane levels run the fused Pallas kernels
+    (`DPF_TPU_LEVEL_KERNEL`); a kernel failure raises. `hash_leaves`
+    fuses the leaf value MMO hash into the same program (single-block
+    value types)."""
     from .utils.runtime import planes_selected
 
     if not planes_selected("DPF_TPU_EXPAND_LEVELS"):
@@ -707,34 +707,24 @@ def _expand_levels_fn(num_levels: int, hash_leaves: bool = False):
     if not mode:
         return _expand_levels_planes_fn(num_levels,
                                         hash_leaves=hash_leaves)
-    if mode == "walk" and not _dep._walk_hier_ok():
-        # The hierarchical geometry (kg=1, node_lanes=prefix words)
-        # carries its own verdict: the base walk verdict never executed
-        # it, and Mosaic legality is shape-dependent. Unverified or
-        # failed -> serve the concat/per-level tiers here. The tail
-        # fallback likewise needs the tail kernel proven at *this*
-        # geometry, not the dense-tile verdict.
-        mode = "tail" if _dep._tail_hier_ok() else "pallas"
     kinds = {}
     if mode == "walk":
         kinds = {
             "tail_kind": "walk",
             "head_kind": "walk",
-            "walk_compact": _dep._walk_compact_ok(),
+            "walk_compact": _dep._walk_compact_enabled(),
         }
     if mode in ("tail", "walk") and hash_leaves:
         # Knobs only enter the cache key when the tail can actually run
         # (hash_leaves), so no-tail programs aren't re-traced per tuple.
-        from .pir.dense_eval_planes import (
-            _tail_levels_requested,
-            _tail_tile_target,
-        )
-
-        tail_req, tail_tile = _tail_levels_requested(), _tail_tile_target()
+        tail_req = _dep._tail_levels_requested()
+        tail_tile = _dep._tail_tile_target()
     else:
         tail_req, tail_tile = 0, 0
-    # Head knobs, resolved at dispatch time (env + self-check state are
-    # process state; the jitted program only sees the cache keys).
+    # Head knobs, resolved at dispatch time (the jitted program only
+    # sees the cache keys): DPF_TPU_HEAD_LEVELS forces a count; walk
+    # mode's head (the walk kernel family) fills to head_cap lanes; the
+    # concat head is not in the TPU auto set.
     raw_head = os.environ.get("DPF_TPU_HEAD_LEVELS", "auto")
     head_cap = _dep._head_max_lanes()
     if raw_head != "auto":
@@ -742,113 +732,15 @@ def _expand_levels_fn(num_levels: int, hash_leaves: bool = False):
             head_req = max(0, int(raw_head))
         except ValueError:
             head_req = 0
-    elif mode == "walk" or (
-        _dep._HEAD_KERNEL_VERIFIED and not _dep._HEAD_KERNEL_FAILED
-    ):
-        # Walk mode's head runs the walk kernel family (gated by the
-        # walk flags via the mode itself), not the concat head.
-        head_req = -1  # auto: fill to head_cap lanes
     else:
-        head_req = 0
-    fast = _expand_levels_planes_fn(num_levels, level_kernel=True,
+        head_req = -1 if mode == "walk" else 0
+    return _expand_levels_planes_fn(num_levels, level_kernel=True,
                                     hash_leaves=hash_leaves,
                                     tail_req=tail_req,
                                     tail_tile_target=tail_tile,
                                     head_req=head_req,
                                     head_cap=head_cap,
                                     **kinds)
-
-    def run_with_fallback(*args):
-        import os as _os
-        import warnings as _warnings
-
-        try:
-            return fast(*args)
-        except Exception as e:  # noqa: BLE001 - degrade, don't die
-            if _os.environ.get("DPF_TPU_LEVEL_KERNEL", "auto") in (
-                "pallas", "tail", "walk"
-            ):
-                raise
-            if kinds:
-                # Walk-mode failure: re-dispatch on the concat/
-                # per-level tiers (their own fallback chain handles
-                # further failures); the walk demotion persists ONLY
-                # after the re-dispatch succeeds — a shared/transient
-                # failure must not burn the fastest tier's
-                # cross-process flag on zero walk-specific evidence.
-                _dep._WALK_KERNEL_FAILED = True
-                try:
-                    out = _expand_levels_fn(
-                        num_levels, hash_leaves=hash_leaves
-                    )(*args)
-                except Exception:  # noqa: BLE001
-                    _dep._WALK_KERNEL_FAILED = False
-                    raise
-                _dep.record_kernel_verdicts()
-                _warnings.warn(
-                    "walk-descent kernels failed in hierarchical "
-                    "expansion; serving without them "
-                    f"({str(e).splitlines()[0][:200]})"
-                )
-                return out
-            if head_req:
-                # Retry without the head, keeping the tail/per-level
-                # kernels. The head is demoted ONLY when the retry
-                # succeeds: head_req may be set while the traced program
-                # resolved the actual head to 0 levels (short segments,
-                # cap below two doublings), and a tail failure there
-                # must not burn the healthy head's process-wide flag.
-                try:
-                    out = _expand_levels_planes_fn(
-                        num_levels, level_kernel=True,
-                        hash_leaves=hash_leaves,
-                        tail_req=tail_req,
-                        tail_tile_target=tail_tile,
-                    )(*args)
-                except Exception as e2:  # noqa: BLE001
-                    e = e2
-                else:
-                    _dep._HEAD_KERNEL_FAILED = True
-                    _dep.record_kernel_verdicts()
-                    _warnings.warn(
-                        "fused head kernel failed in hierarchical "
-                        "expansion; serving without it "
-                        f"({str(e).splitlines()[0][:200]})"
-                    )
-                    return out
-            if tail_req:
-                # A tail failure (e.g. Mosaic rejecting the fused tail
-                # at a big serving shape after the small self-check
-                # passed) degrades to the healthy per-level kernels, not
-                # all the way to XLA; demoted only when that retry
-                # succeeds (a shared failure falls through to the
-                # level-kernel demotion below).
-                try:
-                    out = _expand_levels_planes_fn(
-                        num_levels, level_kernel=True,
-                        hash_leaves=hash_leaves,
-                    )(*args)
-                except Exception as e2:  # noqa: BLE001
-                    e = e2
-                else:
-                    _dep._TAIL_KERNEL_FAILED = True
-                    _dep.record_kernel_verdicts()
-                    _warnings.warn(
-                        "fused tail kernel failed in hierarchical "
-                        "expansion; serving with the per-level kernels "
-                        f"({str(e).splitlines()[0][:200]})"
-                    )
-                    return out
-            _dep._remember_level_kernel_failure()
-            _warnings.warn(
-                "pallas level kernel failed in hierarchical expansion; "
-                f"using the XLA level ({str(e).splitlines()[0][:200]})"
-            )
-            return _expand_levels_planes_fn(
-                num_levels, hash_leaves=hash_leaves
-            )(*args)
-
-    return run_with_fallback
 
 
 @jax.jit
@@ -985,11 +877,8 @@ def _eval_paths(seeds, control, paths, cw_seeds, cw_left, cw_right,
     """Dispatch the path walk: `DPF_TPU_EVAL_PATHS` = `limb` | `planes` |
     `auto` (default: planes on TPU, limb elsewhere — same trade-off as
     `dense_eval.expansion_impl`). On TPU the plane levels run the fused
-    Pallas select-key kernel (`DPF_TPU_LEVEL_KERNEL`), with XLA-level
-    fallback on compile failure."""
-    import os as _os
-    import warnings as _warnings
-
+    Pallas select-key kernel (`DPF_TPU_LEVEL_KERNEL`); a kernel failure
+    raises."""
     from .utils.runtime import planes_selected
 
     if not planes_selected("DPF_TPU_EVAL_PATHS"):
@@ -998,24 +887,9 @@ def _eval_paths(seeds, control, paths, cw_seeds, cw_left, cw_right,
         )
     from .pir import dense_eval_planes as _dep
 
-    if _dep._level_kernel_enabled():
-        try:
-            return _eval_paths_planes(
-                seeds, control, paths, cw_seeds, cw_left, cw_right,
-                bit_indices, level_kernel=True,
-            )
-        except Exception as e:  # noqa: BLE001 - fall back to XLA level
-            if _os.environ.get("DPF_TPU_LEVEL_KERNEL", "auto") in (
-                "pallas", "tail"
-            ):
-                raise
-            _dep._remember_level_kernel_failure()
-            _warnings.warn(
-                "pallas level kernel failed in the path walk; using the "
-                f"XLA level ({str(e).splitlines()[0][:200]})"
-            )
     return _eval_paths_planes(
-        seeds, control, paths, cw_seeds, cw_left, cw_right, bit_indices
+        seeds, control, paths, cw_seeds, cw_left, cw_right, bit_indices,
+        level_kernel=bool(_dep._level_kernel_enabled()),
     )
 
 

@@ -7,13 +7,16 @@ The native library is the framework's CPU oracle — the role the scalar
 differential-tested against it, and host-side tooling can use it without
 JAX.
 
-The shared library is built on demand with `native/build.sh` (g++); the
-binding is plain ctypes — no pybind11 in this environment.
+The shared library is built from the committed sources with
+`native/build.sh` (g++) whenever it is missing or older than any file
+under `native/`; the binding is plain ctypes — no pybind11 in this
+environment.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
 import subprocess
 import threading
@@ -34,12 +37,32 @@ _keys_ctx = None
 _lib_lock = threading.Lock()
 
 
-def _build() -> None:
-    subprocess.run(
-        ["sh", os.path.join(_NATIVE_DIR, "build.sh")],
-        check=True,
-        capture_output=True,
+def _stale() -> bool:
+    """True when the library is missing or older than any source."""
+    try:
+        built = os.path.getmtime(_LIB_PATH)
+    except OSError:
+        return True
+    return any(
+        os.path.getmtime(os.path.join(_NATIVE_DIR, name)) > built
+        for name in os.listdir(_NATIVE_DIR)
+        if not name.startswith(".") and name != "libdpf_native.so"
     )
+
+
+def _build_if_stale() -> None:
+    """Rebuild under an exclusive file lock, so concurrent processes
+    (test workers on a fresh checkout) build once and the rest wait."""
+    if not _stale():
+        return
+    with open(os.path.join(_NATIVE_DIR, ".build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if _stale():
+            subprocess.run(
+                ["sh", os.path.join(_NATIVE_DIR, "build.sh")],
+                check=True,
+                capture_output=True,
+            )
 
 
 def _u8(arr) -> np.ndarray:
@@ -61,8 +84,7 @@ def get_lib() -> ctypes.CDLL:
 
 def _load_locked() -> ctypes.CDLL:
     global _lib, _keys_ctx
-    if not os.path.exists(_LIB_PATH):
-        _build()
+    _build_if_stale()
     lib = ctypes.CDLL(_LIB_PATH)
     lib.dpf_create_keys.restype = ctypes.c_void_p
     lib.dpf_create_keys.argtypes = [ctypes.c_char_p] * 3

@@ -11,7 +11,7 @@ device facts):
   recorder retaining the slowest/errored traces, process-wide stage
   aggregates, and runtime counters for layers below serving.
 * `phases` — per-request latency attribution into a canonical phase
-  taxonomy (queue -> batch -> h2d_transfer -> compile -> dispatch ->
+  catalog (queue -> batch -> h2d_transfer -> compile -> dispatch ->
   device_compute -> helper_rtt -> respond), aggregated per role into
   the `/statusz` waterfall.
 * `device` — compile-event tracker (one compile per new dispatch

@@ -731,9 +731,8 @@ class DeviceTelemetry:
         self.transfers = TransferLedger(registry)
         # Optional persistent-compilation-cache info: a dict, or a
         # zero-arg callable returning one (re-counted per scrape).
-        # Installed by the serving startup when
-        # DPF_TPU_COMPILE_CACHE_DIR wires the JAX cache, so /statusz's
-        # compile table can show cold-vs-warm counts.
+        # Installed by the serving startup (`install_compile_cache`),
+        # so /statusz's compile table can show cold-vs-warm counts.
         self._compile_cache_info = None
 
     def bind_registry(self, registry) -> None:

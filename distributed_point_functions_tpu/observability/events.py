@@ -11,7 +11,7 @@ a sequence number, wall + monotonic timestamps, a kind, a severity,
 and the active trace id when one exists — surfaced at `/eventz`, as a
 tail section on `/statusz`, and snapshotted into debug bundles.
 
-Event kinds emitted by the library (the taxonomy; see DESIGN.md §15):
+Event kinds emitted by the library (the catalog; see DESIGN.md §15):
 
     breaker.transition     Leader helper-leg breaker state change
     service.degraded       Leader entered leader-share-only degraded mode

@@ -6,7 +6,7 @@ item 3 question — *where does a millisecond of request latency go* —
 because spans are free-form (names differ per call site, nest, and
 overlap) and device counters have no per-request denominator. This
 module adds the canonical decomposition: every request accumulates
-milliseconds into a fixed phase taxonomy
+milliseconds into a fixed phase catalog
 
     queue          waiting in the batcher admission queue
     batch          batch-assembly window (waiting for co-batchable
@@ -375,8 +375,8 @@ class PhaseRecorder:
         """{role: {requests, end_to_end_ms: {...}, phases: {phase:
         {count, total_ms, mean_ms, p50_ms, p99_ms, max_ms, share}}}}
         where `share` is the phase's fraction of the role's summed
-        end-to-end time. Phases are ordered by the canonical taxonomy,
-        then alphabetically for any out-of-taxonomy names."""
+        end-to-end time. Phases are ordered by the canonical catalog,
+        then alphabetically for any out-of-catalog names."""
         with self._lock:
             agg = {
                 role: {name: (e[0], e[1], list(e[2]))

@@ -62,7 +62,7 @@ __all__ = [
     "set_default_utilization_tracker",
 ]
 
-# The typed bubble taxonomy (DESIGN.md §21). Unknown causes from
+# The typed bubble catalog (DESIGN.md §21). Unknown causes from
 # duck-typed reporters degrade to "other" instead of raising.
 BUBBLE_CAUSES = (
     "empty_queue",

@@ -23,9 +23,9 @@ and are tiled across the node-major lane axis in VMEM via
 so a whole-array tile repeats every KG lanes.
 
 Everything is differentially tested against the XLA twins in interpret
-mode (`tests/test_expand_pallas.py`) and re-verified on hardware before
-serving (`pir/dense_eval_planes.py` falls back to the XLA level on any
-compile failure).
+mode (`tests/test_expand_pallas.py`); the TPU auto set is AOT-compiled
+for v5e in `tests/test_chip_compile.py` and bit-checked once on the
+device before serving (`pir/dense_eval_planes.py`; a mismatch raises).
 
 Reference semantics: `ExpandSeeds`
 (`dpf/distributed_point_function.cc:289-372`).

@@ -54,6 +54,17 @@ def pack_selection_bits_np(bits: np.ndarray) -> np.ndarray:
     return out
 
 
+def varying_zeros(shape, *like) -> jnp.ndarray:
+    """uint32 zeros that vary over the same shard_map axes as `like`.
+
+    A scan carry started from plain zeros is unvarying; inside shard_map
+    the body's XOR with sharded operands makes it varying, and the carry
+    types must match. Outside shard_map this is just `jnp.zeros`."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in like))
+    zeros = jnp.zeros(shape, dtype=U32)
+    return lax.pcast(zeros, tuple(vma), to="varying") if vma else zeros
+
+
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def xor_inner_product(
     db_words: jnp.ndarray, selections: jnp.ndarray, chunk: int = 256
@@ -81,7 +92,7 @@ def xor_inner_product(
             masked, U32(0), lambda a, b: lax.bitwise_xor(a, b), (1,)
         )
 
-    acc = jnp.zeros((selections.shape[0], num_words), dtype=U32)
+    acc = varying_zeros((selections.shape[0], num_words), bits, db_words)
     if num_chunks > 0:
         db_main = db_words[: num_chunks * chunk].reshape(
             num_chunks, chunk, num_words

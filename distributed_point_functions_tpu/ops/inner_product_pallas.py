@@ -29,12 +29,13 @@ value-bit-planes are peeled in VMEM (`(dbb >> j) & 1`) and hit the MXU as
 query batch.
 
 Exactness bound: counts accumulate in f32, so the kernel requires
-R <= 2^24 records (far above the 2^22 headline config); the caller falls
-back to the jnp path beyond that.
+R <= 2^24 records (far above the 2^22 headline config); the v2 kernel
+(`xor_inner_product_pallas2_staged`, int8 with exact int32 counts, the
+one TPU serving uses) has no such cap.
 
 Differentially tested against the jnp implementation and the numpy/native
-oracles (tests/test_pallas.py); bit-identity vs the jnp path is re-checked
-on hardware by bench.py before the kernel serves the measured run.
+oracles (tests/test_pallas.py); AOT-compiled for v5e at the serving shape
+in tests/test_chip_compile.py.
 """
 
 from __future__ import annotations
@@ -50,51 +51,6 @@ from jax.experimental.pallas import tpu as pltpu
 U32 = jnp.uint32
 I32 = jnp.int32
 
-# jax < 0.6 has no varying-manual-axes metadata on ShapeDtypeStruct; its
-# shard_map fallback runs with the replication checker off instead
-# (parallel/sharded.py), so dropping the annotation there is consistent.
-try:
-    jax.ShapeDtypeStruct((1,), U32, vma=frozenset())
-    _HAS_VMA = True
-except TypeError:
-    _HAS_VMA = False
-
-_INTERPRET_REPEAT_TILES: bool | None = None
-
-
-def _interpret_repeat_tiles() -> bool:
-    """Whether interpret-mode `pltpu.repeat` tiles the source like Mosaic
-    (`[a b] -> [a b a b]`).
-
-    Old jax interpreted it as element-wise `np.repeat` (`[a a b b]`),
-    silently corrupting every kernel below under interpret=True; those
-    kernels swap in a concat-based tile when this probe says so. The
-    compiled path always has Mosaic semantics and is never rerouted.
-    """
-    global _INTERPRET_REPEAT_TILES
-    if _INTERPRET_REPEAT_TILES is None:
-        def probe(x_ref, o_ref):
-            o_ref[:] = pltpu.repeat(x_ref[:], 2, axis=1)
-
-        # The probe must run eagerly even when first reached while
-        # tracing the jitted caller.
-        with jax.ensure_compile_time_eval():
-            got = pl.pallas_call(
-                probe,
-                out_shape=jax.ShapeDtypeStruct((1, 4), U32),
-                interpret=True,
-            )(jnp.arange(2, dtype=U32)[None, :])
-            _INTERPRET_REPEAT_TILES = bool(
-                (got[0] == jnp.array([0, 1, 0, 1], dtype=U32)).all()
-            )
-    return _INTERPRET_REPEAT_TILES
-
-
-def _tile_repeat(x, factor: int, axis: int):
-    """Mosaic-semantics repeat (whole-source tiling along `axis`)."""
-    if factor == 1:
-        return x
-    return jnp.concatenate([x] * factor, axis=axis)
 I8 = jnp.int8
 BF16 = jnp.bfloat16
 F32 = jnp.float32
@@ -282,10 +238,7 @@ def xor_inner_product_pallas_staged(
     return out[:nq] if nq_pad != nq else out
 
 
-def _ip_kernel_v2(
-    sel_ref, db_ref, out_ref, *, j_chunk: int, int8: bool,
-    repeat=pltpu.repeat,
-):
+def _ip_kernel_v2(sel_ref, db_ref, out_ref, *, j_chunk: int, int8: bool):
     """One large MXU dot per (grid step, value-bit chunk).
 
     v1 (`_ip_kernel`) issues 32x32 = 1024 tiny [TQ, TG] x [TG, W] dots per
@@ -323,7 +276,7 @@ def _ip_kernel_v2(
         as_i32 = bits_u32.astype(I32)
         return as_i32.astype(I8) if int8 else as_i32.astype(F32).astype(BF16)
 
-    sel_rep = repeat(sel_ref[:], 32, axis=1)  # [TQ, 32*TG] tiled
+    sel_rep = pltpu.repeat(sel_ref[:], 32, axis=1)  # [TQ, 32*TG] tiled
     b_iota = lax.broadcasted_iota(U32, (tq, tr), 1) // U32(tg)
     lhs = to_mm((sel_rep >> b_iota) & U32(1))
 
@@ -332,14 +285,14 @@ def _ip_kernel_v2(
     # sidestepping Mosaic's narrow-source repeat miscompile (the entry
     # point drops to 1 for W<16 records; whether a factor-1 repeat on a
     # narrow source is really legal is UNPROBED on hardware —
-    # benchmarks/kernel_smoke.py's W=8 case answers it, and the serving
-    # tier chain degrades to the v1 kernel if it crashes). The repeat
+    # benchmarks/kernel_smoke.py's W=8 case answers it; a crash raises
+    # out of serving). The repeat
     # also launders shard_map's varying-axes metadata exactly like the
     # multi-factor path: a direct ref read would carry the mesh axis and
     # mismatch the unvarying iotas and constants throughout the kernel
     # (the VMA checker runs at trace time on any backend; the declared
     # out_shape vma covers the result).
-    db_rep = repeat(dbw, j_chunk, axis=1)
+    db_rep = pltpu.repeat(dbw, j_chunk, axis=1)
     acc_t = I32 if int8 else F32
     for jc in range(0, 32, j_chunk):
         if j_chunk == 1:
@@ -397,11 +350,6 @@ def _ip_pallas_staged_v2(
     counts = pl.pallas_call(
         functools.partial(
             _ip_kernel_v2, j_chunk=j_chunk, int8=int8,
-            repeat=(
-                _tile_repeat
-                if interpret and not _interpret_repeat_tiles()
-                else pltpu.repeat
-            ),
         ),
         grid=(nq // tq, num_groups // tg),
         in_specs=[
@@ -415,7 +363,7 @@ def _ip_pallas_staged_v2(
         # checker on (the multi-chip MXU step, `parallel/sharded.py`).
         out_shape=jax.ShapeDtypeStruct(
             (nq, 32 * num_words), acc_t,
-            **({"vma": frozenset(vma)} if (vma and _HAS_VMA) else {}),
+            **({"vma": frozenset(vma)} if vma else {}),
         ),
         interpret=interpret,
     )(packed, db_perm)

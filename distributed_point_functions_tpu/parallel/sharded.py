@@ -36,20 +36,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax < 0.6 only exports shard_map from jax.experimental, and its
-# replication checker predates the varying-manual-axes metadata the
-# pallas inner-product declares on new jax — run it with the checker
-# off there (the new-jax default-on check_vma path still covers these
-# programs wherever jax.shard_map exists).
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return _experimental_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+shard_map = jax.shard_map
 
 from ..ops.inner_product import unpack_selection_bits
 from ..pir.dense_eval import expansion_impl
@@ -246,9 +233,8 @@ def sharded_dense_pir_step_multi(
             "blocks may lie beyond the tree"
         )
     # The expansion below runs inside a shard_map trace, where the level
-    # kernels' on-device self-check cannot run; warm it here, eagerly, so
-    # the traced expansion serves the verified Pallas kernels instead of
-    # silently dropping to the XLA levels.
+    # kernels' on-device self-check cannot run; run it here, eagerly, so
+    # a kernel that miscompiles raises before the traced program builds.
     from ..pir.dense_eval_planes import warm_level_kernels
 
     warm_level_kernels()
@@ -431,9 +417,8 @@ def sharded_dense_pir_step_mxu(
             "blocks may lie beyond the tree"
         )
     # The expansion below runs inside a shard_map trace, where the level
-    # kernels' on-device self-check cannot run; warm it here, eagerly, so
-    # the traced expansion serves the verified Pallas kernels instead of
-    # silently dropping to the XLA levels.
+    # kernels' on-device self-check cannot run; run it here, eagerly, so
+    # a kernel that miscompiles raises before the traced program builds.
     from ..pir.dense_eval_planes import warm_level_kernels
 
     warm_level_kernels()

@@ -8,19 +8,19 @@ record is zero-padded to the maximum record size, and the record count is
 padded to a multiple of 128 so whole selection blocks line up with rows.
 
 `inner_product_with` serves the whole query batch in one database pass
-through a tier chain: on TPU the v2 Pallas MXU kernel first
-(`ops/inner_product_pallas.py:xor_inner_product_pallas2_staged`, one
-large int8 dot per tile, bit-major layout staged once on first use);
-then the v1 Pallas kernel and the pure-jnp MXU bit-plane path (same
-math, no Mosaic dependency; both f32-exact only to 2^24 records); and
-finally — elsewhere (CPU tests) or on any failure — the jitted jnp
-XOR-reduction. Set
-``DPF_TPU_INNER_PRODUCT=pallas2|pallas|bitplane|jnp`` to force a tier
-(forced tiers propagate their errors instead of falling through).
+through ONE inner-product tier (`inner_product_tier`): on TPU the v2
+Pallas MXU kernel (`ops/inner_product_pallas.py:
+xor_inner_product_pallas2_staged`, one large int8 dot per tile,
+bit-major layout staged once on first use); elsewhere (CPU tests) the
+jitted jnp XOR-reduction. A failure raises: no tier stands in for
+another. ``DPF_TPU_INNER_PRODUCT=pallas2|pallas|bitplane|jnp`` forces a
+tier (the v1 Pallas kernel and the pure-jnp MXU bit-plane path are
+f32-exact only to 2^24 records).
 """
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
 import time
@@ -39,7 +39,6 @@ from ..ops.inner_product import (
     xor_inner_product_bitplane,
 )
 from ..ops.inner_product_pallas import (
-    MAX_RECORDS_EXACT,
     permute_db_bitmajor,
     xor_inner_product_pallas2_staged,
     xor_inner_product_pallas_staged,
@@ -76,6 +75,31 @@ def _v2_tile_knobs() -> dict:
 
 
 _PIPELINE_ENV = "DPF_TPU_PIPELINED_STAGING"
+
+
+def inner_product_tier() -> str:
+    """The inner-product tier this process serves: pallas2 on TPU, jnp
+    elsewhere, or the tier `DPF_TPU_INNER_PRODUCT` forces."""
+    mode = os.environ.get("DPF_TPU_INNER_PRODUCT", "auto")
+    if mode == "auto":
+        return "pallas2" if jax.default_backend() == "tpu" else "jnp"
+    return mode
+
+
+def tier_inner_product(tier: str):
+    """(db, selections) -> uint32[nq, W] for `tier`; every tier but jnp
+    reads the bit-major staging (`permute_db_bitmajor`)."""
+    if tier == "pallas2":
+        return functools.partial(
+            xor_inner_product_pallas2_staged, **_v2_tile_knobs()
+        )
+    if tier == "pallas":
+        return xor_inner_product_pallas_staged
+    if tier == "bitplane":
+        return xor_inner_product_bitplane
+    if tier == "jnp":
+        return xor_inner_product
+    raise ValueError(f"unknown DPF_TPU_INNER_PRODUCT tier {tier!r}")
 
 
 def pipelined_staging_enabled() -> bool:
@@ -248,9 +272,9 @@ class DenseDpfPirDatabase:
         self._init_runtime()
 
     def _init_runtime(self) -> None:
-        """Device-staging slots and tier-fallback memory (fresh per
-        instance — a delta build shares host bytes, never stagings)."""
-        self._db_words = None  # row-major device copy (jnp fallback path)
+        """Device-staging slots (fresh per instance — a delta build
+        shares host bytes, never stagings)."""
+        self._db_words = None  # row-major device copy (jnp tier)
         self._db_perm = None  # bit-major layout, staged on first pallas use
         # Bitrev-block staging (the v2 gather-free serving exit): same
         # records with 128-record blocks bit-reversal-permuted, padded
@@ -271,8 +295,6 @@ class DenseDpfPirDatabase:
         # full HBM copy). Reentrant because _staged_perm -> _row_words
         # -> _host_words_bitrev nest.
         self._stage_lock = threading.RLock()
-        self._failed_tiers: set = set()
-        self._failed_knobs: set = set()  # v2 knob combos that crashed
         # Delta-build lineage (`_from_delta` fills these): the previous
         # generation (weakly, so a rotation chain never retains every
         # ancestor's host image) and the sorted updated row indices.
@@ -901,93 +923,14 @@ class DenseDpfPirDatabase:
         }
         return arr
 
-    def _tier_chain(self):
-        """(tiers-to-try, forced): the inner-product fallback chain.
-
-        Auto mode on TPU: the v2 Pallas kernel (one large int8 MXU dot
-        per tile, exact int32 counts — no record cap below int32 range),
-        then the v1 Pallas kernel and the pure-jnp bit-plane path (both
-        f32-exact only to 2^24 records), then the jnp XOR reduction.
-        A forced tier propagates its errors instead of falling through.
-        """
-        mode = os.environ.get("DPF_TPU_INNER_PRODUCT", "auto")
-        if mode != "auto":
-            return [mode], True
-        chain = []
-        if jax.default_backend() == "tpu":
-            chain.append("pallas2")
-            if self._num_padded <= MAX_RECORDS_EXACT:
-                chain += ["pallas", "bitplane"]
-        chain.append("jnp")
-        return chain, False
-
     def _inner_product_device(
         self, selections: jnp.ndarray, bitrev_blocks: bool = False
     ) -> jnp.ndarray:
-        chain, forced = self._tier_chain()
-        for tier in chain:
-            # Remembered failures: a failed trace/compile is not cached
-            # by jit, so retrying would pay it on every batch.
-            if tier in self._failed_tiers:
-                continue
-            try:
-                if tier == "pallas2":
-                    knobs = _v2_tile_knobs()
-                    knob_key = tuple(sorted(knobs.items()))
-                    if knob_key in self._failed_knobs:
-                        knobs, knob_key = {}, ()
-                    try:
-                        return xor_inner_product_pallas2_staged(
-                            self._staged_perm(bitrev_blocks), selections,
-                            **knobs
-                        )
-                    except Exception as e:  # noqa: BLE001
-                        # The positivity pre-check above cannot know the
-                        # kernel's real tile floors/multiples; a
-                        # positive-but-unsupported knob (e.g. TG below the
-                        # 16-lane miscompile floor) must cost ONE retry
-                        # with defaults — remembered, so later batches go
-                        # straight to the defaults (a failed trace is not
-                        # cached by jit) — not the pallas2 tier itself.
-                        if not knobs:
-                            raise
-                        self._failed_knobs.add(knob_key)
-                        warnings.warn(
-                            "pallas2 failed with env tile knobs "
-                            f"{knobs}; retrying with kernel defaults "
-                            f"({str(e).splitlines()[0][:200]})"
-                        )
-                        return xor_inner_product_pallas2_staged(
-                            self._staged_perm(bitrev_blocks), selections
-                        )
-                if tier == "pallas":
-                    return xor_inner_product_pallas_staged(
-                        self._staged_perm(bitrev_blocks), selections
-                    )
-                if tier == "bitplane":
-                    return xor_inner_product_bitplane(
-                        self._staged_perm(bitrev_blocks), selections
-                    )
-                if tier == "jnp":
-                    return xor_inner_product(
-                        self._row_words(bitrev_blocks), selections
-                    )
-                raise ValueError(
-                    f"unknown DPF_TPU_INNER_PRODUCT tier {tier!r}"
-                )
-            except Exception as e:  # noqa: BLE001 - fall through the chain
-                if forced or tier == "jnp":
-                    raise
-                self._failed_tiers.add(tier)
-                if tier == chain[-2]:
-                    # jnp path reads row-major only.
-                    self._db_perm = None
-                    self._db_perm_rev = None
-                warnings.warn(
-                    f"{tier} inner product failed; falling back "
-                    f"({str(e).splitlines()[0][:200]})"
-                )
-        raise AssertionError("unreachable: jnp tier returns or raises")
+        tier = inner_product_tier()
+        inner_product = tier_inner_product(tier)
+        if tier == "jnp":
+            return inner_product(self._row_words(bitrev_blocks), selections)
+        return inner_product(self._staged_perm(bitrev_blocks), selections)
 
     def inner_product_with(
         self, selections: jnp.ndarray, *, bitrev_blocks: bool = False

@@ -40,10 +40,8 @@ subtree, as in `dense_eval.py`.
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import os
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -209,8 +207,8 @@ def evaluate_selection_blocks_planes(
     The key axis is padded to a multiple of 32 and the dead lanes double
     along with the live ones at every level, so a batch of e.g. 3 queries
     would pay ~10x the AES work. When the padding overhead exceeds 25%,
-    fall back to the limb kernel (which pads per 32-block hash call and
-    reaches full occupancy once the width fills a word).
+    such batches are served by the limb kernel (which pads per 32-block
+    hash call and reaches full occupancy once the width fills a word).
     `force_planes=True` bypasses the guard (differential tests)."""
     nk = seeds0.shape[0]
     padded = ((nk + 31) // 32) * 32
@@ -224,14 +222,14 @@ def evaluate_selection_blocks_planes(
             num_blocks=num_blocks,
         )
     mode = _level_kernel_enabled()
+    tail_levels = tile_nodes = head_levels = 0
+    tail_kind = head_kind = "concat"
     if mode:
         # Walk mode runs the fixed-width descent kernels (head + tail);
         # tail mode fuses the last levels + value hash per subtree tile
         # (one kernel launch each); the fused head covers the narrow
         # entry levels in one launch; the per-level kernels (if any
-        # levels remain) cover the middle.
-        tail_levels = tile_nodes = 0
-        tail_kind = head_kind = "concat"
+        # levels remain) cover the middle. A kernel that fails raises.
         kg = padded // 32
         if mode == "walk" and not bitrev_leaves:
             # The walk kernels exit in natural leaf order, which the
@@ -241,134 +239,27 @@ def evaluate_selection_blocks_planes(
             tail_kind = head_kind = "walk"
             tail_levels = min(_tail_levels_requested(), expand_levels)
             head_levels = _head_split(
-                kg, expand_levels - tail_levels, gate_flags=False
+                kg, expand_levels - tail_levels, walk=True
             )
         else:
             if mode == "tail" and not bitrev_leaves:
                 tail_levels, tile_nodes = _tail_split(kg, expand_levels)
             head_levels = _head_split(kg, expand_levels - tail_levels)
-        forced = os.environ.get("DPF_TPU_LEVEL_KERNEL", "auto") in (
-            "pallas", "tail", "walk"
-        )
-        global _HEAD_KERNEL_FAILED, _TAIL_KERNEL_FAILED
-        global _WALK_KERNEL_FAILED
-        try:
-            return _evaluate_selection_blocks_planes_jit(
-                seeds0, control0, cw_seeds, cw_left, cw_right, last_vc,
-                walk_levels=walk_levels,
-                expand_levels=expand_levels,
-                num_blocks=num_blocks,
-                bitrev_leaves=bitrev_leaves,
-                level_kernel=True,
-                tail_levels=tail_levels,
-                tail_tile_nodes=tile_nodes,
-                head_levels=head_levels,
-                tail_kind=tail_kind,
-                head_kind=head_kind,
-                walk_compact=(
-                    tail_kind == "walk" and _walk_compact_ok()
-                ),
-            )
-        except Exception as e:  # noqa: BLE001 - degrade, don't die
-            if forced:
-                raise
-            if tail_kind == "walk":
-                # Walk-mode failure: re-enter the dispatcher without
-                # the walk family (the concat/per-level tiers' own
-                # degradation chain handles any further failures). The
-                # demotion is persisted ONLY after the re-dispatch
-                # succeeds — mirroring the head/tail attribution rule:
-                # a shared/transient failure must not burn the fastest
-                # tier's cross-process flag on zero walk-specific
-                # evidence.
-                _WALK_KERNEL_FAILED = True
-                try:
-                    out = evaluate_selection_blocks_planes(
-                        seeds0, control0, cw_seeds, cw_left,
-                        cw_right, last_vc,
-                        walk_levels=walk_levels,
-                        expand_levels=expand_levels,
-                        num_blocks=num_blocks,
-                        bitrev_leaves=bitrev_leaves,
-                        force_planes=force_planes,
-                    )
-                except Exception:  # noqa: BLE001
-                    _WALK_KERNEL_FAILED = False
-                    raise
-                record_kernel_verdicts()
-                warnings.warn(
-                    "walk-descent kernels failed at serving shape; "
-                    "serving without them "
-                    f"({str(e).splitlines()[0][:200]})"
-                )
-                return out
-            if head_levels:
-                # Retry without the head, keeping the tail. The head is
-                # demoted ONLY when this retry succeeds — a shared
-                # failure (e.g. the tail is the culprit) must not burn
-                # the healthy head's process-wide flag on zero evidence.
-                try:
-                    out = _evaluate_selection_blocks_planes_jit(
-                        seeds0, control0, cw_seeds, cw_left, cw_right,
-                        last_vc,
-                        walk_levels=walk_levels,
-                        expand_levels=expand_levels,
-                        num_blocks=num_blocks,
-                        bitrev_leaves=bitrev_leaves,
-                        level_kernel=True,
-                        tail_levels=tail_levels,
-                        tail_tile_nodes=tile_nodes,
-                    )
-                except Exception as e2:  # noqa: BLE001
-                    e = e2
-                else:
-                    _HEAD_KERNEL_FAILED = True
-                    record_kernel_verdicts()
-                    warnings.warn(
-                        "fused head kernel failed at serving shape; "
-                        "serving without it "
-                        f"({str(e).splitlines()[0][:200]})"
-                    )
-                    return out
-            if tail_levels:
-                # Retry on the per-level kernels alone (no head, no
-                # tail); the tail is demoted only when that succeeds —
-                # if this fails too, the level-kernel failure below
-                # already disables the whole family.
-                try:
-                    out = _evaluate_selection_blocks_planes_jit(
-                        seeds0, control0, cw_seeds, cw_left, cw_right,
-                        last_vc,
-                        walk_levels=walk_levels,
-                        expand_levels=expand_levels,
-                        num_blocks=num_blocks,
-                        bitrev_leaves=bitrev_leaves,
-                        level_kernel=True,
-                    )
-                except Exception as e2:  # noqa: BLE001
-                    e = e2
-                else:
-                    _TAIL_KERNEL_FAILED = True
-                    record_kernel_verdicts()
-                    warnings.warn(
-                        "fused tail kernel failed at serving shape; "
-                        "serving with the per-level kernels "
-                        f"({str(e).splitlines()[0][:200]})"
-                    )
-                    return out
-            _remember_level_kernel_failure()
-            warnings.warn(
-                "pallas level kernel failed; serving via the XLA level "
-                f"({str(e).splitlines()[0][:200]})"
-            )
     return _evaluate_selection_blocks_planes_jit(
         seeds0, control0, cw_seeds, cw_left, cw_right, last_vc,
         walk_levels=walk_levels,
         expand_levels=expand_levels,
         num_blocks=num_blocks,
         bitrev_leaves=bitrev_leaves,
-        level_kernel=False,
+        level_kernel=bool(mode),
+        tail_levels=tail_levels,
+        tail_tile_nodes=tile_nodes,
+        head_levels=head_levels,
+        tail_kind=tail_kind,
+        head_kind=head_kind,
+        walk_compact=tail_kind == "walk" and _walk_compact_enabled(),
     )
+
 
 
 def _trace_state_clean() -> bool:
@@ -380,176 +271,6 @@ def _trace_state_clean() -> bool:
         return bool(_core.trace_state_clean())
     except Exception:  # noqa: BLE001 - jax internals moved
         return True
-
-
-_LEVEL_KERNEL_FAILED = False
-_WARNED_TRACED_UNVERIFIED = False
-
-
-def _remember_level_kernel_failure() -> None:
-    """Disable the auto-mode Pallas level kernel for this process (a
-    failed trace is not cached by jit, so retrying would pay it on every
-    batch)."""
-    global _LEVEL_KERNEL_FAILED
-    _LEVEL_KERNEL_FAILED = True
-    record_kernel_verdicts()
-
-
-_VERDICTS_LOADED = False
-_VERDICT_FLAGS = (
-    "_LEVEL_KERNEL_VERIFIED", "_LEVEL_KERNEL_FAILED",
-    "_TAIL_KERNEL_VERIFIED", "_TAIL_KERNEL_FAILED",
-    "_HEAD_KERNEL_VERIFIED", "_HEAD_KERNEL_FAILED",
-    "_WALK_KERNEL_VERIFIED", "_WALK_KERNEL_FAILED",
-    "_WALK_COMPACT_VERIFIED", "_WALK_COMPACT_FAILED",
-    "_WALK_HIER_VERIFIED", "_WALK_HIER_FAILED",
-    "_TAIL_HIER_VERIFIED", "_TAIL_HIER_FAILED",
-)
-
-
-def _verdict_cache_path():
-    """Where self-check verdicts persist across processes.
-
-    A Mosaic compile *failure* costs minutes of doomed remote-compile
-    per fresh process (r04 hardware: the failing tail self-check alone
-    burned ~4 minutes of every bench run before this cache existed);
-    XLA's compilation cache memoizes successes but never failures.
-    DPF_TPU_VERDICT_CACHE overrides the location; 0/off disables."""
-    raw = os.environ.get("DPF_TPU_VERDICT_CACHE", "")
-    if raw.lower() in ("0", "off", "none"):
-        return None
-    if raw:
-        return raw
-    return os.path.join(
-        os.environ.get(
-            "BENCH_CACHE_DIR", os.path.expanduser("~/.cache/jax_bench")
-        ),
-        "kernel_verdicts.json",
-    )
-
-
-def _verdict_key():
-    """Verdicts are only valid for the exact (device kind, jax/jaxlib/
-    runtime version, kernel source) tuple — Mosaic lives in jaxlib and
-    the platform runtime, so a toolchain upgrade must re-probe: a stale
-    VERIFIED would skip the bit-identity check under a compiler that may
-    now miscompile, and a stale FAILED would demote kernels forever
-    after the upgrade fixes the compile."""
-    try:
-        import hashlib
-
-        import jaxlib
-
-        from ..ops import aes_bitslice as _abs
-        from ..ops import expand_planes_pallas as _epp
-
-        h = hashlib.sha256()
-        for mod in (_epp, _abs):  # kernels + the gate circuit they call
-            with open(mod.__file__, "rb") as f:
-                h.update(f.read())
-        dev = jax.devices()[0]
-        try:
-            runtime = dev.client.platform_version
-        except Exception:  # noqa: BLE001 - backend without the attr
-            runtime = ""
-        return (
-            f"{dev.device_kind}|{jax.__version__}|{jaxlib.__version__}"
-            f"|{runtime}|{h.hexdigest()[:16]}"
-        )
-    except Exception:  # noqa: BLE001 - cache is best-effort
-        return None
-
-
-def _load_kernel_verdicts() -> None:
-    """Apply persisted verdicts (once per process) before self-checks."""
-    global _VERDICTS_LOADED
-    if _VERDICTS_LOADED:
-        return
-    _VERDICTS_LOADED = True
-    path = _verdict_cache_path()
-    if not path:
-        return
-    key = _verdict_key()
-    if not key:
-        return
-    try:
-        import json
-
-        with open(path) as f:
-            stored = json.load(f).get(key)
-    except Exception:  # noqa: BLE001 - missing/corrupt cache = re-probe
-        return
-    if not isinstance(stored, dict):
-        return
-    for flag in _VERDICT_FLAGS:
-        if stored.get(flag) is True:
-            globals()[flag] = True
-
-
-_LAST_RECORDED = None
-_RECORD_SUSPENDED = False
-
-
-@contextlib.contextmanager
-def suspend_verdict_recording():
-    """Silence the persistent verdict cache while a caller holds
-    SPECULATIVE flag state (the bench demotion ladder sets a tier's
-    FAILED flag before its attribution retry, and the retry itself
-    triggers record_kernel_verdicts via warm_level_kernels /
-    _level_kernel_enabled — without this guard a budget abort would
-    leave an evidence-free demotion on disk forever)."""
-    global _RECORD_SUSPENDED
-    prev = _RECORD_SUSPENDED
-    _RECORD_SUSPENDED = True
-    try:
-        yield
-    finally:
-        _RECORD_SUSPENDED = prev
-
-
-def record_kernel_verdicts() -> None:
-    """Merge the current self-check flags into the persistent cache.
-
-    Called after every verdict change (self-check pass/fail and
-    serve-shape demotions, including dpf.py's hierarchical path), so
-    the next process skips known-failing Mosaic compiles instantly."""
-    global _LAST_RECORDED
-    if _RECORD_SUSPENDED:
-        return
-    snapshot = tuple(bool(globals()[f]) for f in _VERDICT_FLAGS)
-    if snapshot == _LAST_RECORDED:
-        # Repeated eager dispatches land here after every successful
-        # _level_kernel_enabled(); skip the re-hash + rewrite when
-        # nothing changed.
-        return
-    path = _verdict_cache_path()
-    if not path:
-        return
-    key = _verdict_key()
-    if not key:
-        return
-    try:
-        import json
-
-        try:
-            with open(path) as f:
-                data = json.load(f)
-        except Exception:  # noqa: BLE001
-            data = {}
-        if not isinstance(data, dict):
-            data = {}
-        entry = data.setdefault(key, {})
-        for flag in _VERDICT_FLAGS:
-            if globals()[flag]:
-                entry[flag] = True
-        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-        tmp = f"{path}.{os.getpid()}.tmp"
-        with open(tmp, "w") as f:
-            json.dump(data, f, indent=1)
-        os.replace(tmp, path)
-        _LAST_RECORDED = snapshot
-    except Exception:  # noqa: BLE001 - cache is best-effort
-        pass
 
 
 _LEVEL_KERNEL_VERIFIED = False
@@ -640,12 +361,6 @@ def _level_kernel_selfcheck() -> bool:
     return True
 
 
-_TAIL_KERNEL_VERIFIED = False
-_TAIL_KERNEL_FAILED = False
-_HEAD_KERNEL_VERIFIED = False
-_HEAD_KERNEL_FAILED = False
-
-
 def _head_max_lanes() -> int:
     """Exit-width cap for the fused head kernel (DPF_TPU_HEAD_MAX_LANES,
     default 2048: the in-kernel working set is ~6 copies of the widest
@@ -668,21 +383,14 @@ def _auto_head_count(cap: int, entry_lanes: int, avail: int) -> int:
     return head if head >= 2 else 0
 
 
-def _head_split(
-    key_groups: int, a_levels: int, gate_flags: bool = True
-) -> int:
+def _head_split(key_groups: int, a_levels: int, walk: bool = False) -> int:
     """How many entry levels the fused head kernel covers (0 = no head).
 
-    The head runs from `key_groups` lanes until its exit width reaches
-    the VMEM lane cap (or the per-level/tail boundary). A 1-level head
-    is just a worse per-level launch, so the minimum is 2.
-    DPF_TPU_HEAD_LEVELS forces the count (0 disables) — honored even
-    before the self-check has run, so forced A/B legs
-    (DPF_TPU_LEVEL_KERNEL=pallas|tail|walk) can measure the head; a
-    failure then propagates (forced) or demotes the head (auto).
-    `gate_flags=False` skips the concat-head verification gate — walk
-    mode's head runs the walk kernel family, gated by the walk flags
-    through the mode itself."""
+    DPF_TPU_HEAD_LEVELS forces the count (0 disables). Otherwise only
+    walk mode has a head — its walk-kernel head runs from `key_groups`
+    lanes until its exit width reaches the VMEM lane cap (a 1-level
+    head is just a worse per-level launch, so the minimum is 2); the
+    concat head is not in the TPU auto set (`TPU_AUTO_LEVEL_KERNEL`)."""
     if a_levels <= 0:
         return 0
     raw = os.environ.get("DPF_TPU_HEAD_LEVELS", "auto")
@@ -691,618 +399,9 @@ def _head_split(
             return max(0, min(int(raw), a_levels))
         except ValueError:
             pass
-    if gate_flags and (_HEAD_KERNEL_FAILED or not _HEAD_KERNEL_VERIFIED):
+    if not walk:
         return 0
     return _auto_head_count(_head_max_lanes(), key_groups, a_levels)
-
-
-def _head_kernel_selfcheck() -> bool:
-    """One-time on-device bit-identity check of the fused head kernel
-    against sequential XLA levels. The head's serving entry is naturally
-    narrow (key_groups lanes), so the check runs at a matching narrow
-    entry — the shape family it actually serves."""
-    global _HEAD_KERNEL_VERIFIED, _HEAD_KERNEL_FAILED
-    if _HEAD_KERNEL_FAILED:
-        return False
-    if _HEAD_KERNEL_VERIFIED:
-        return True
-    import numpy as _np
-
-    rng = _np.random.default_rng(9876)
-    g0, nk, r = 2, 64, 3
-    state = jnp.asarray(
-        rng.integers(0, 1 << 32, (16, 8, g0), dtype=_np.uint32)
-    )
-    ctrl = jnp.asarray(rng.integers(0, 1 << 32, (g0,), dtype=_np.uint32))
-    cwp = [
-        pack_key_planes(jnp.asarray(
-            rng.integers(0, 1 << 32, (nk, 4), dtype=_np.uint32)
-        ))
-        for _ in range(r)
-    ]
-    cwl = [
-        pack_key_bits(jnp.asarray(
-            rng.integers(0, 2, (nk,), dtype=_np.uint32)
-        ))
-        for _ in range(r)
-    ]
-    cwr = [
-        pack_key_bits(jnp.asarray(
-            rng.integers(0, 2, (nk,), dtype=_np.uint32)
-        ))
-        for _ in range(r)
-    ]
-    s, c = state, ctrl
-    for i in range(r):
-        g2 = 2 * s.shape[-1]
-        s, c = expand_level_planes(
-            s, c, _tile_keys(cwp[i], g2), _tile_keys(cwl[i], g2 // 2),
-            _tile_keys(cwr[i], g2 // 2),
-        )
-    got_s, got_c = expand_head_planes_pallas(
-        state, ctrl, jnp.stack(cwp), jnp.stack(cwl), jnp.stack(cwr)
-    )
-    if not (
-        _np.array_equal(_np.asarray(got_s), _np.asarray(s))
-        and _np.array_equal(_np.asarray(got_c), _np.asarray(c))
-    ):
-        raise RuntimeError("head kernel/XLA bit mismatch on this device")
-    _HEAD_KERNEL_VERIFIED = True
-    return True
-
-
-_WALK_KERNEL_VERIFIED = False
-_WALK_KERNEL_FAILED = False
-# Mosaic legality/miscompiles are shape- and mode-dependent (the walk
-# redesign exists because of that), so the base walk verdict must NOT
-# green-light geometries it never executed: compact-entry mode and the
-# hierarchical kg=1/node_lanes=prefix-words layout carry their own
-# verdicts, each bit-verified in exactly the mode the dispatcher would
-# launch (ADVICE r04).
-_WALK_COMPACT_VERIFIED = False
-_WALK_COMPACT_FAILED = False
-_WALK_HIER_VERIFIED = False
-_WALK_HIER_FAILED = False
-# Tail kernel at the HIERARCHICAL operand geometry (kg=1 shared
-# corrections, zero value correction — dpf.py's fused program): its own
-# verdict pair, because the dense-tile _TAIL_KERNEL_VERIFIED never
-# executed those operand shapes and Mosaic legality is shape-dependent.
-_TAIL_HIER_VERIFIED = False
-_TAIL_HIER_FAILED = False
-
-
-def _walk_twin_instance(rng, g0, nk, r):
-    """Random walk-phase instance + its doubling-twin result: returns
-    (state, ctrl, cwp[r], cwl[r], cwr[r], vc, want_v, want_c). The twin
-    runs the sequential XLA levels and the leaf value hash — the ground
-    truth every walk-geometry self-check compares against."""
-    import numpy as _np
-
-    state = jnp.asarray(
-        rng.integers(0, 1 << 32, (16, 8, g0), dtype=_np.uint32)
-    )
-    ctrl = jnp.asarray(rng.integers(0, 1 << 32, (g0,), dtype=_np.uint32))
-    if nk is None:
-        # Hierarchical layout: one key, shared correction words.
-        from ..ops.aes_bitslice import broadcast_cw_planes
-
-        cwp = [
-            broadcast_cw_planes(jnp.asarray(
-                rng.integers(0, 1 << 32, (4,), dtype=_np.uint32)
-            ))
-            for _ in range(r)
-        ]
-        cwl = [
-            (U32(0) - jnp.asarray(rng.integers(0, 2), dtype=U32))[None]
-            for _ in range(r)
-        ]
-        cwr = [
-            (U32(0) - jnp.asarray(rng.integers(0, 2), dtype=U32))[None]
-            for _ in range(r)
-        ]
-        vc = jnp.zeros((16, 8, 1), dtype=U32)  # dpf.py's zero-vc tail
-    else:
-        cwp = [
-            pack_key_planes(jnp.asarray(
-                rng.integers(0, 1 << 32, (nk, 4), dtype=_np.uint32)
-            ))
-            for _ in range(r)
-        ]
-        cwl = [
-            pack_key_bits(jnp.asarray(
-                rng.integers(0, 2, (nk,), dtype=_np.uint32)
-            ))
-            for _ in range(r)
-        ]
-        cwr = [
-            pack_key_bits(jnp.asarray(
-                rng.integers(0, 2, (nk,), dtype=_np.uint32)
-            ))
-            for _ in range(r)
-        ]
-        vc = pack_key_planes(jnp.asarray(
-            rng.integers(0, 1 << 32, (nk, 4), dtype=_np.uint32)
-        ))
-    s, c = state, ctrl
-    for i in range(r):
-        g2 = 2 * s.shape[-1]
-        s, c = expand_level_planes(
-            s, c, _tile_keys(cwp[i], g2), _tile_keys(cwl[i], g2 // 2),
-            _tile_keys(cwr[i], g2 // 2),
-        )
-    want_v = mmo_hash_planes(fixed_keys.RK_VALUE, s) ^ (
-        _tile_keys(vc, s.shape[-1]) & c[None, None, :]
-    )
-    return state, ctrl, cwp, cwl, cwr, vc, want_v, c
-
-
-def _walk_twin_lanes(exit_order, r, n_entry, node_lanes):
-    """Lane gather mapping a walk exit order onto the doubling twin:
-    lane block p of the walk output holds leaf `exit_order[p]`, which
-    the twin placed at position argsort(doubling order)[leaf]."""
-    import numpy as _np
-
-    order = tail_node_permutation(
-        _np.arange(n_entry), r, n_entry
-    )[0]
-    pos_of_leaf = _np.argsort(order)
-    pos = pos_of_leaf[_np.asarray(exit_order)]
-    return (
-        pos[:, None] * node_lanes + _np.arange(node_lanes)[None, :]
-    ).reshape(-1)
-
-
-# Self-check instance shapes. Hardware verdicts must come from the
-# SERVING tile geometry (Mosaic legality is shape-dependent), so these
-# stay at the production widths; the CPU interpret-mode tests shrink
-# them via monkeypatch (an interpret kernel call costs ~15-30 s
-# regardless of correctness).
-_WALK_SELFCHECK_SHAPE = dict(g0=1024, nk=64, r=2, tile=2048)
-_WALK_COMPACT_SELFCHECK_SHAPE = dict(g0=1024, nk=64, r=2)
-_WALK_HIER_SELFCHECK_SHAPE = dict(nl=4, n_entry=64, r=2)
-_TAIL_SELFCHECK_SHAPE = dict(g0=256, nk=64, r=2, tile=128)
-_TAIL_HIER_SELFCHECK_SHAPE = dict(g0=256, r=2, tile=128)
-
-
-def _walk_kernel_selfcheck() -> bool:
-    """One-time on-device bit-identity check of the fixed-width
-    walk-descent kernel (2 levels + value hash, 2 tiles) against the
-    doubling XLA twin, at the SERVING tile width (2048 lanes — Mosaic
-    legality is shape-dependent, so a verdict from a smaller tile would
-    not cover the geometry the dispatcher actually picks)."""
-    global _WALK_KERNEL_VERIFIED, _WALK_KERNEL_FAILED
-    if _WALK_KERNEL_FAILED:
-        return False
-    if _WALK_KERNEL_VERIFIED:
-        return True
-    import numpy as _np
-
-    rng = _np.random.default_rng(2468)
-    s = _WALK_SELFCHECK_SHAPE
-    g0, nk, r, tile = s["g0"], s["nk"], s["r"], s["tile"]
-    kg = nk // 32
-    state, ctrl, cwp, cwl, cwr, vc, want_v, want_c = _walk_twin_instance(
-        rng, g0, nk, r
-    )
-    # Replicated mode exits in natural leaf order.
-    lanes = _walk_twin_lanes(
-        _np.arange((g0 // kg) << r), r, g0 // kg, kg
-    )
-    got_v, got_c = walk_descend_planes_pallas(
-        state, ctrl, jnp.stack(cwp), jnp.stack(cwl), jnp.stack(cwr),
-        vc, r=r, tile_lanes=tile, value_hash=True,
-    )
-    if not (
-        _np.array_equal(
-            _np.asarray(got_v), _np.asarray(want_v)[:, :, lanes]
-        )
-        and _np.array_equal(
-            _np.asarray(got_c), _np.asarray(want_c)[lanes]
-        )
-    ):
-        raise RuntimeError("walk kernel/XLA bit mismatch on this device")
-    _WALK_KERNEL_VERIFIED = True
-    return True
-
-
-def _walk_compact_selfcheck() -> bool:
-    """One-time on-device bit-identity check of the walk kernel's
-    COMPACT-ENTRY mode at the dense-serving geometry (node_lanes = kg),
-    in exactly the tile/mode `walk_plan` would pick. The base walk
-    verdict never executed this mode, and a compact-mode miscompile
-    would serve wrong PIR shares under a 'verified' flag."""
-    global _WALK_COMPACT_VERIFIED, _WALK_COMPACT_FAILED
-    if _WALK_COMPACT_FAILED:
-        return False
-    if _WALK_COMPACT_VERIFIED:
-        return True
-    import numpy as _np
-
-    from ..ops.expand_planes_pallas import (
-        compose_walk_leaf_order,
-        walk_plan,
-    )
-
-    rng = _np.random.default_rng(97531)
-    s = _WALK_COMPACT_SELFCHECK_SHAPE
-    g0, nk, r = s["g0"], s["nk"], s["r"]
-    kg = nk // 32
-    tile, compact, npt = walk_plan(g0 << r, kg, kg, r, True)
-    if not compact:
-        # walk_plan declined compact at this geometry (tile cap): the
-        # mode cannot launch here, so there is nothing to verify — and
-        # nothing FAILED. Returning False (instead of raising into the
-        # caller's except clause) keeps the cross-process FAILED verdict
-        # reserved for genuine kernel evidence; a decline is a planner
-        # decision that can change with tile knobs or jax versions.
-        return False
-    state, ctrl, cwp, cwl, cwr, vc, want_v, want_c = _walk_twin_instance(
-        rng, g0, nk, r
-    )
-    n_entry = g0 // kg
-    exit_order = compose_walk_leaf_order(
-        _np.arange(n_entry, dtype=_np.int64), r, True, npt
-    )
-    lanes = _walk_twin_lanes(exit_order, r, n_entry, kg)
-    got_v, got_c = walk_descend_planes_pallas(
-        state, ctrl, jnp.stack(cwp), jnp.stack(cwl), jnp.stack(cwr),
-        vc, r=r, tile_lanes=tile, value_hash=True, compact_entry=True,
-    )
-    if not (
-        _np.array_equal(
-            _np.asarray(got_v), _np.asarray(want_v)[:, :, lanes]
-        )
-        and _np.array_equal(
-            _np.asarray(got_c), _np.asarray(want_c)[lanes]
-        )
-    ):
-        raise RuntimeError(
-            "compact walk kernel/XLA bit mismatch on this device"
-        )
-    _WALK_COMPACT_VERIFIED = True
-    return True
-
-
-def _walk_hier_selfcheck() -> bool:
-    """One-time on-device bit-identity check of the walk kernel at the
-    HIERARCHICAL geometry (kg=1 shared corrections, node_lanes =
-    prefix words, zero value correction — `dpf._expand_levels_planes_fn`'s
-    layout), in exactly the tile/mode its `walk_order` would plan."""
-    global _WALK_HIER_VERIFIED, _WALK_HIER_FAILED
-    if _WALK_HIER_FAILED:
-        return False
-    if _WALK_HIER_VERIFIED:
-        return True
-    import numpy as _np
-
-    from ..ops.expand_planes_pallas import (
-        compose_walk_leaf_order,
-        walk_plan,
-    )
-
-    rng = _np.random.default_rng(86420)
-    s = _WALK_HIER_SELFCHECK_SHAPE
-    nl, n_entry, r = s["nl"], s["n_entry"], s["r"]
-    g0 = nl * n_entry
-    state, ctrl, cwp, cwl, cwr, vc, want_v, want_c = _walk_twin_instance(
-        rng, g0, None, r
-    )
-    # Verify every mode the hierarchical dispatch could launch —
-    # replicated AND compact, regardless of the env knob: the persisted
-    # _WALK_HIER_VERIFIED flag outlives this process, and a later
-    # process with DPF_TPU_WALK_COMPACT=1 would otherwise dispatch a
-    # tile/mode combination no self-check ever executed. (walk_plan may
-    # decline compact at this geometry, collapsing both plans into one.)
-    plans = []
-    for want_compact in (False, True):
-        plan = walk_plan(g0 << r, 1, nl, r, want_compact)
-        if plan not in plans:
-            plans.append(plan)
-    for tile, compact, npt in plans:
-        exit_order = compose_walk_leaf_order(
-            _np.arange(n_entry, dtype=_np.int64), r, compact, npt
-        )
-        lanes = _walk_twin_lanes(exit_order, r, n_entry, nl)
-        got_v, got_c = walk_descend_planes_pallas(
-            state, ctrl, jnp.stack(cwp), jnp.stack(cwl), jnp.stack(cwr),
-            vc, r=r, tile_lanes=tile, value_hash=True, node_lanes=nl,
-            compact_entry=compact,
-        )
-        if not (
-            _np.array_equal(
-                _np.asarray(got_v), _np.asarray(want_v)[:, :, lanes]
-            )
-            and _np.array_equal(
-                _np.asarray(got_c), _np.asarray(want_c)[lanes]
-            )
-        ):
-            raise RuntimeError(
-                "hierarchical walk kernel/XLA bit mismatch on this "
-                f"device (compact={compact})"
-            )
-    _WALK_HIER_VERIFIED = True
-    return True
-
-
-def _walk_compact_ok() -> bool:
-    """Gate for compact-entry walk mode at dispatch time: requested via
-    the env knob AND bit-verified in that exact mode. Under an active
-    trace the self-check cannot run; only a prior eager verification
-    counts (mirroring `_level_kernel_enabled`'s trace rule)."""
-    global _WALK_COMPACT_FAILED
-    if not _walk_compact_enabled():
-        return False
-    if _WALK_COMPACT_FAILED:
-        return False
-    if _WALK_COMPACT_VERIFIED:
-        return True
-    if not _trace_state_clean():
-        return False
-    try:
-        return _walk_compact_selfcheck()
-    except Exception as e:  # noqa: BLE001 - never break serving
-        _WALK_COMPACT_FAILED = True
-        record_kernel_verdicts()
-        warnings.warn(
-            "compact-entry walk mode failed its on-device self-check; "
-            f"serving replicated entries ({str(e).splitlines()[0][:200]})"
-        )
-        return False
-
-
-def _walk_hier_ok() -> bool:
-    """Gate for the hierarchical walk geometry at dispatch time (same
-    trace/verification rules as `_walk_compact_ok`)."""
-    global _WALK_HIER_FAILED
-    if _WALK_HIER_FAILED:
-        return False
-    if _WALK_HIER_VERIFIED:
-        return True
-    if not _trace_state_clean():
-        return False
-    try:
-        return _walk_hier_selfcheck()
-    except Exception as e:  # noqa: BLE001 - never break serving
-        _WALK_HIER_FAILED = True
-        record_kernel_verdicts()
-        warnings.warn(
-            "hierarchical walk geometry failed its on-device "
-            f"self-check; serving the concat/per-level tiers there "
-            f"({str(e).splitlines()[0][:200]})"
-        )
-        return False
-
-
-def _tail_kernel_selfcheck() -> bool:
-    """One-time on-device bit-identity check of the fused tail kernel
-    (2 levels + value hash over 2 tiles) against the XLA twin. Separate
-    from `_level_kernel_selfcheck` so a tail-only failure degrades auto
-    mode to the per-level kernels instead of all the way to XLA."""
-    global _TAIL_KERNEL_VERIFIED, _TAIL_KERNEL_FAILED
-    # FAILED wins over VERIFIED: a serving-shape failure recorded after a
-    # successful self-check must demote the tail for the whole process
-    # (jit does not cache failed traces, so re-attempting pays the full
-    # compile on every request).
-    if _TAIL_KERNEL_FAILED:
-        return False
-    if _TAIL_KERNEL_VERIFIED:
-        return True
-    import numpy as _np
-
-    rng = _np.random.default_rng(4321)
-    # Entry tile of 128 lanes (2 tiles, so the multi-tile assembly is
-    # exercised): serving tiles are >=128 lanes by _tail_split's floor,
-    # and Mosaic's known crash regime is narrow lanes — a self-check at
-    # 4-lane tiles could fail (and permanently demote the tail) at a
-    # shape the tail never serves.
-    s = _TAIL_SELFCHECK_SHAPE
-    g0, nk, r, tile = s["g0"], s["nk"], s["r"], s["tile"]
-    state = jnp.asarray(
-        rng.integers(0, 1 << 32, (16, 8, g0), dtype=_np.uint32)
-    )
-    ctrl = jnp.asarray(rng.integers(0, 1 << 32, (g0,), dtype=_np.uint32))
-    cwp = [
-        pack_key_planes(jnp.asarray(
-            rng.integers(0, 1 << 32, (nk, 4), dtype=_np.uint32)
-        ))
-        for _ in range(r)
-    ]
-    cwl = [
-        pack_key_bits(jnp.asarray(
-            rng.integers(0, 2, (nk,), dtype=_np.uint32)
-        ))
-        for _ in range(r)
-    ]
-    cwr = [
-        pack_key_bits(jnp.asarray(
-            rng.integers(0, 2, (nk,), dtype=_np.uint32)
-        ))
-        for _ in range(r)
-    ]
-    vc = pack_key_planes(jnp.asarray(
-        rng.integers(0, 1 << 32, (nk, 4), dtype=_np.uint32)
-    ))
-    want_vs, want_cs = [], []
-    for lo in range(0, g0, tile):
-        s = state[:, :, lo:lo + tile]
-        c = ctrl[lo:lo + tile]
-        for i in range(r):
-            g2 = 2 * s.shape[-1]
-            s, c = expand_level_planes(
-                s, c, _tile_keys(cwp[i], g2), _tile_keys(cwl[i], g2 // 2),
-                _tile_keys(cwr[i], g2 // 2),
-            )
-        want_vs.append(
-            mmo_hash_planes(fixed_keys.RK_VALUE, s)
-            ^ (_tile_keys(vc, s.shape[-1]) & c[None, None, :])
-        )
-        want_cs.append(c)
-    got_v, got_c = expand_tail_planes_pallas(
-        state, ctrl, jnp.stack(cwp), jnp.stack(cwl), jnp.stack(cwr), vc,
-        tile_lanes=tile,
-    )
-    if not (
-        _np.array_equal(
-            _np.asarray(got_v),
-            _np.asarray(jnp.concatenate(want_vs, axis=-1)),
-        )
-        and _np.array_equal(
-            _np.asarray(got_c), _np.asarray(jnp.concatenate(want_cs))
-        )
-    ):
-        raise RuntimeError("tail kernel/XLA bit mismatch on this device")
-    _TAIL_KERNEL_VERIFIED = True
-    return True
-
-
-def _tail_hier_selfcheck() -> bool:
-    """One-time on-device bit-identity check of the fused tail kernel at
-    the HIERARCHICAL operand geometry (`dpf._expand_levels_planes_fn`'s
-    tail: kg=1 broadcast correction planes, [1]-shaped direction words,
-    zero value correction) against the XLA twin. `_TAIL_KERNEL_VERIFIED`
-    comes from per-key dense-tile operands and does not cover these
-    shapes."""
-    global _TAIL_HIER_VERIFIED, _TAIL_HIER_FAILED
-    if _TAIL_HIER_FAILED:
-        return False
-    if _TAIL_HIER_VERIFIED:
-        return True
-    import numpy as _np
-
-    from ..ops.aes_bitslice import broadcast_cw_planes
-
-    rng = _np.random.default_rng(8642)
-    s = _TAIL_HIER_SELFCHECK_SHAPE
-    g0, r, tile = s["g0"], s["r"], s["tile"]
-    state = jnp.asarray(
-        rng.integers(0, 1 << 32, (16, 8, g0), dtype=_np.uint32)
-    )
-    ctrl = jnp.asarray(rng.integers(0, 1 << 32, (g0,), dtype=_np.uint32))
-    cwp = [
-        broadcast_cw_planes(jnp.asarray(
-            rng.integers(0, 1 << 32, (4,), dtype=_np.uint32)
-        ))
-        for _ in range(r)
-    ]
-    cwl = [
-        (U32(0) - jnp.asarray(rng.integers(0, 2), dtype=U32))[None]
-        for _ in range(r)
-    ]
-    cwr = [
-        (U32(0) - jnp.asarray(rng.integers(0, 2), dtype=U32))[None]
-        for _ in range(r)
-    ]
-    vc = jnp.zeros((16, 8, 1), dtype=U32)
-    want_vs, want_cs = [], []
-    for lo in range(0, g0, tile):
-        st = state[:, :, lo:lo + tile]
-        c = ctrl[lo:lo + tile]
-        for i in range(r):
-            g2 = 2 * st.shape[-1]
-            st, c = expand_level_planes(
-                st, c, _tile_keys(cwp[i], g2), _tile_keys(cwl[i], g2 // 2),
-                _tile_keys(cwr[i], g2 // 2),
-            )
-        want_vs.append(
-            mmo_hash_planes(fixed_keys.RK_VALUE, st)
-            ^ (_tile_keys(vc, st.shape[-1]) & c[None, None, :])
-        )
-        want_cs.append(c)
-    got_v, got_c = expand_tail_planes_pallas(
-        state, ctrl, jnp.stack(cwp), jnp.stack(cwl), jnp.stack(cwr), vc,
-        tile_lanes=tile,
-    )
-    if not (
-        _np.array_equal(
-            _np.asarray(got_v),
-            _np.asarray(jnp.concatenate(want_vs, axis=-1)),
-        )
-        and _np.array_equal(
-            _np.asarray(got_c), _np.asarray(jnp.concatenate(want_cs))
-        )
-    ):
-        raise RuntimeError(
-            "hierarchical-geometry tail kernel/XLA bit mismatch on this "
-            "device"
-        )
-    _TAIL_HIER_VERIFIED = True
-    return True
-
-
-def _tail_hier_ok() -> bool:
-    """Gate for the tail kernel at the hierarchical operand geometry
-    (same trace/verification rules as `_walk_hier_ok`): dpf.py's
-    walk-mode fallback must not trust the dense-tile tail verdict across
-    geometries."""
-    global _TAIL_HIER_FAILED
-    if _TAIL_HIER_FAILED:
-        return False
-    if _TAIL_HIER_VERIFIED:
-        return True
-    if not _trace_state_clean():
-        return False
-    try:
-        return _tail_hier_selfcheck()
-    except Exception as e:  # noqa: BLE001 - never break serving
-        _TAIL_HIER_FAILED = True
-        record_kernel_verdicts()
-        warnings.warn(
-            "hierarchical-geometry tail kernel failed its on-device "
-            f"self-check; serving the per-level tiers there "
-            f"({str(e).splitlines()[0][:200]})"
-        )
-        return False
-
-
-def warm_level_kernels():
-    """Eagerly run the kernel self-checks (and return the serving mode).
-
-    `_level_kernel_enabled` cannot self-check while an outer jit/shard_map
-    trace is active — it then reports the last *eager* verification, which
-    on a fresh process is "nothing verified" and silently serves the XLA
-    levels. Callers that trace the expansion into a bigger program
-    (bench.py's fused step, the sharded mesh step) call this once, from
-    eager context, before building the traced program."""
-    mode = _level_kernel_enabled()
-    if mode == "walk":
-        # The compact-entry and hierarchical geometries carry their own
-        # verdicts: warm them here so traced programs (the fused serving
-        # step, the sharded mesh step, bench's ns/leaf hierarchical
-        # stage) can dispatch them — the in-trace gates only honor a
-        # prior eager verification.
-        if _walk_compact_enabled():
-            _walk_compact_ok()
-        if not _walk_hier_ok():
-            # dpf.py's walk fallback re-dispatches the hierarchical tail
-            # through the fused tail kernel when ITS geometry verdict
-            # holds; warm that verdict too so the traced program can
-            # still take the tail tier.
-            _tail_hier_ok()
-    elif mode == "tail":
-        _tail_hier_ok()
-    return mode
-
-
-def level_kernel_status() -> dict:
-    """Public observability snapshot for benches/captures: the serving
-    mode knob and the one-time self-check flags."""
-    return {
-        "mode": os.environ.get("DPF_TPU_LEVEL_KERNEL", "auto"),
-        "verified": _LEVEL_KERNEL_VERIFIED,
-        "failed": _LEVEL_KERNEL_FAILED,
-        "tail_verified": _TAIL_KERNEL_VERIFIED,
-        "tail_failed": _TAIL_KERNEL_FAILED,
-        "head_verified": _HEAD_KERNEL_VERIFIED,
-        "head_failed": _HEAD_KERNEL_FAILED,
-        "walk_verified": _WALK_KERNEL_VERIFIED,
-        "walk_failed": _WALK_KERNEL_FAILED,
-        "walk_compact_verified": _WALK_COMPACT_VERIFIED,
-        "walk_compact_failed": _WALK_COMPACT_FAILED,
-        "walk_hier_verified": _WALK_HIER_VERIFIED,
-        "walk_hier_failed": _WALK_HIER_FAILED,
-        "tail_hier_verified": _TAIL_HIER_VERIFIED,
-        "tail_hier_failed": _TAIL_HIER_FAILED,
-    }
 
 
 def _tail_levels_requested() -> int:
@@ -1362,102 +461,53 @@ def _tail_split(
     return tail, tile_nodes(expand_levels - tail)
 
 
+# The level-kernel set the TPU auto path serves, fixed from what the v5e
+# compiler accepts at the serving shape (tests/test_chip_compile.py):
+# the per-level expansion and value-hash kernels. The fused concat head
+# and tail are refused there ("Input offsets outside of the first
+# tile"), and the walk-descent program did not finish compiling in 25
+# minutes, so both stay behind DPF_TPU_LEVEL_KERNEL.
+TPU_AUTO_LEVEL_KERNEL = "pallas"
+
+
 def _level_kernel_enabled():
     """Whether (and how) the fused Pallas kernels serve the expansion:
-    False, "pallas" (per-level kernels), "tail" (per-level kernels plus
-    the fused multi-level tail + value hash), or "walk" (fixed-width
-    walk-descent head + tail).
+    False (XLA levels), "pallas" (per-level kernels), "tail" (per-level
+    kernels plus the fused multi-level tail + value hash), or "walk"
+    (fixed-width walk-descent head + tail).
 
-    DPF_TPU_LEVEL_KERNEL=pallas|tail|walk forces the mode (errors
-    propagate), =xla disables it; auto prefers walk > tail > per-level
-    on TPU after one-time on-device bit-identity self-checks, until a
-    remembered failure."""
-    global _TAIL_KERNEL_FAILED, _WALK_KERNEL_FAILED
+    DPF_TPU_LEVEL_KERNEL=pallas|tail|walk forces the mode, =xla disables
+    it; auto serves `TPU_AUTO_LEVEL_KERNEL` on TPU and the XLA levels
+    elsewhere. Outside a trace, auto mode bit-checks its kernels once
+    (`_level_kernel_selfcheck`), and a mismatch raises."""
     mode = os.environ.get("DPF_TPU_LEVEL_KERNEL", "auto")
     if mode in ("pallas", "tail", "walk"):
         return mode
-    if mode == "xla":
+    if mode == "xla" or jax.default_backend() != "tpu":
         return False
-    if jax.default_backend() != "tpu":
-        return False
-    _load_kernel_verdicts()
-    if _LEVEL_KERNEL_FAILED:
-        return False
-    if not _trace_state_clean():
-        # Reached while an outer jit is being traced (e.g. the fused DCF
-        # program calling the path walk): the self-check cannot run here —
-        # its jitted twins would be traced into the outer program and the
-        # comparisons would explode on tracers. Report the last *eager*
-        # verification result; never record a failure from this path.
-        # Forgetting to warm is a silent perf cliff (the r02 headline
-        # served XLA levels this way), so make it loud exactly once.
-        if not _LEVEL_KERNEL_VERIFIED:
-            global _WARNED_TRACED_UNVERIFIED
-            if not _WARNED_TRACED_UNVERIFIED:
-                _WARNED_TRACED_UNVERIFIED = True
-                warnings.warn(
-                    "expansion traced before warm_level_kernels(): the "
-                    "Pallas level kernels are unverified in this process "
-                    "and this program will serve the XLA levels — call "
-                    "dense_eval_planes.warm_level_kernels() from eager "
-                    "context before building traced programs"
-                )
-            return False
-        if _WALK_KERNEL_VERIFIED and not _WALK_KERNEL_FAILED:
-            return "walk"
-        return (
-            "tail"
-            if _TAIL_KERNEL_VERIFIED and not _TAIL_KERNEL_FAILED
-            else "pallas"
-        )
-    try:
-        if not _level_kernel_selfcheck():
-            return False
-    except Exception as e:  # noqa: BLE001 - never break serving
-        _remember_level_kernel_failure()
-        warnings.warn(
-            "pallas level kernels failed their on-device self-check; "
-            f"serving via the XLA levels ({str(e).splitlines()[0][:200]})"
-        )
-        return False
-    # The fused head is orthogonal to the tail/per-level choice: verify
-    # it here so `_head_split` can enable it inside traced programs. A
-    # head-only failure costs nothing but the head.
-    global _HEAD_KERNEL_FAILED
-    try:
-        _head_kernel_selfcheck()
-    except Exception as e:  # noqa: BLE001 - never break serving
-        _HEAD_KERNEL_FAILED = True
-        warnings.warn(
-            "fused head kernel failed its on-device self-check; "
-            f"serving without it ({str(e).splitlines()[0][:200]})"
-        )
-    # Prefer the walk-descent kernels (fixed-width, no doubling
-    # constructs) when they verify on this device; then the fused tail;
-    # a fused-kernel failure degrades to the per-level kernels, not XLA.
-    try:
-        if _walk_kernel_selfcheck():
-            record_kernel_verdicts()
-            return "walk"
-    except Exception as e:  # noqa: BLE001 - never break serving
-        _WALK_KERNEL_FAILED = True
-        warnings.warn(
-            "walk-descent kernel failed its on-device self-check; "
-            f"trying the fused tail ({str(e).splitlines()[0][:200]})"
-        )
-    try:
-        if _tail_kernel_selfcheck():
-            record_kernel_verdicts()
-            return "tail"
-    except Exception as e:  # noqa: BLE001 - never break serving
-        _TAIL_KERNEL_FAILED = True
-        warnings.warn(
-            "fused tail kernel failed its on-device self-check; "
-            f"serving via the per-level kernels "
-            f"({str(e).splitlines()[0][:200]})"
-        )
-    record_kernel_verdicts()
-    return "pallas"
+    if not _LEVEL_KERNEL_VERIFIED and _trace_state_clean():
+        _level_kernel_selfcheck()
+    return TPU_AUTO_LEVEL_KERNEL
+
+
+def warm_level_kernels():
+    """Run the auto mode's one-time kernel self-check from eager
+    context (and return the serving mode). Callers that trace the
+    expansion into a bigger program (the sharded mesh step, the
+    hierarchical evaluator) call this first: inside a trace the check
+    cannot run."""
+    return _level_kernel_enabled()
+
+
+def level_kernel_status() -> dict:
+    """The serving mode knob, the mode it resolves to, and whether the
+    auto mode's self-check has passed in this process."""
+    return {
+        "mode": os.environ.get("DPF_TPU_LEVEL_KERNEL", "auto"),
+        "serving": _level_kernel_enabled() or "xla",
+        "verified": _LEVEL_KERNEL_VERIFIED,
+    }
+
 
 
 @functools.partial(

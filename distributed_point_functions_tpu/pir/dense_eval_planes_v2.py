@@ -56,7 +56,7 @@ from ..ops.aes_bitslice import (
     planes_to_limbs,
     sigma_planes,
 )
-from ..ops.inner_product import xor_inner_product_accumulate
+from ..ops.inner_product import varying_zeros, xor_inner_product_accumulate
 from ..ops.inner_product_pallas import xor_inner_product_pallas2_accumulate
 from .dense_eval import _walk_zeros
 from .dense_eval_planes import (
@@ -373,7 +373,7 @@ def streaming_scan_accumulate(
         return acc, None
 
     nkp = state.shape[0] * 32
-    acc0 = jnp.zeros((nkp, db_chunks.shape[-1]), U32)
+    acc0 = varying_zeros((nkp, db_chunks.shape[-1]), st_x, ct_x, db_chunks)
     acc, _ = jax.lax.scan(body, acc0, (db_chunks, st_x, ct_x))
     return acc
 

@@ -276,9 +276,9 @@ class DenseDpfPirServer(DpfPirServer):
         self._sharded_step = None
         self._sharded_db = None
         # 2-D mesh serving plan (shard axis x key axis): built lazily on
-        # the first request; a build failure or device OOM parks the
-        # error here and the tier-demotion chain falls back to
-        # single-device for the rest of the process.
+        # the first request (a build failure raises); a device OOM parks
+        # the error here and the OOM demotion serves single-device for
+        # the rest of the process.
         self._mesh_plan = None
         self._mesh_db = None
         self._mesh_plan_error = None
@@ -291,7 +291,6 @@ class DenseDpfPirServer(DpfPirServer):
         self._mesh_exec_lock = threading.Lock()
         self._chunked_db = None
         self._chunked_db_lock = threading.Lock()
-        self._streaming_ip_failed = False
         # Runtime tier demotion: num_keys -> minimum tier index in
         # _TIERS after a device OOM proved the budget model optimistic
         # for that batch shape.
@@ -469,7 +468,7 @@ class DenseDpfPirServer(DpfPirServer):
         seen = telemetry.compile_tracker.seen
         if self._mesh_is_2d():
             inner_products = self._serve_mesh(keys, telemetry, seen)
-            if inner_products is None:  # plan infeasible / device OOM
+            if inner_products is None:  # mesh demoted by a device OOM
                 inner_products = self._serve_single_device(
                     keys, bitrev, impl, telemetry, seen
                 )
@@ -643,10 +642,8 @@ class DenseDpfPirServer(DpfPirServer):
     def _plan_serving(self, num_keys: int, bitrev: bool) -> ServingPlan:
         """One planner call decides materialized / streaming / chunked
         and the streaming cut/chunk split (see `planner.py` for the HBM
-        budget model). A remembered streaming inner-product failure
-        (e.g. a Mosaic compile crash) demotes the scan tier to jnp for
-        the rest of the process, and a remembered device OOM for this
-        batch shape pins the planner at a lower tier."""
+        budget model). A remembered device OOM for this batch shape
+        pins the planner at a lower tier."""
         import jax
 
         plan = plan_dense_serving(
@@ -666,10 +663,6 @@ class DenseDpfPirServer(DpfPirServer):
                 backend=jax.default_backend(),
                 force_mode=self._TIERS[floor],
             )
-        if plan.mode == "streaming" and self._streaming_ip_failed:
-            import dataclasses
-
-            plan = dataclasses.replace(plan, ip="jnp")
         return plan
 
     def _selection_budget_bytes(self) -> int:
@@ -698,35 +691,19 @@ class DenseDpfPirServer(DpfPirServer):
         with phases_mod.phase("h2d_transfer"):
             staged, device_walk = stage_keys_walked(keys, self._walk_levels)
 
-        def run(ip: str):
-            db_chunks = self._database.streaming_chunks(
-                cut_levels=plan.cut_levels, bitmajor=(ip == "pallas2")
+        db_chunks = self._database.streaming_chunks(
+            cut_levels=plan.cut_levels, bitmajor=(plan.ip == "pallas2")
+        )
+        out = np.asarray(
+            streaming_pir_inner_products_v2(
+                *staged,
+                db_chunks,
+                walk_levels=device_walk,
+                cut_levels=plan.cut_levels,
+                chunk_levels=plan.chunk_levels,
+                ip=plan.ip,
             )
-            return np.asarray(
-                streaming_pir_inner_products_v2(
-                    *staged,
-                    db_chunks,
-                    walk_levels=device_walk,
-                    cut_levels=plan.cut_levels,
-                    chunk_levels=plan.chunk_levels,
-                    ip=ip,
-                )
-            )
-
-        try:
-            out = run(plan.ip)
-        except Exception as e:  # noqa: BLE001 - demote the scan tier once
-            if plan.ip == "jnp":
-                raise
-            self._streaming_ip_failed = True
-            tracing.runtime_counters.inc("pir.streaming_ip_demotions")
-            import warnings
-
-            warnings.warn(
-                f"streaming {plan.ip} inner product failed; falling back "
-                f"to the jnp scan tier ({str(e).splitlines()[0][:200]})"
-            )
-            out = run("jnp")
+        )
         return words_to_record_bytes(
             out, num_keys, self._database.max_value_size
         )
@@ -822,26 +799,22 @@ class DenseDpfPirServer(DpfPirServer):
 
     def _ensure_mesh_plan(self, num_keys_hint: int):
         """Build (once) the 2-D serving plan and the mesh-sharded
-        database staging. Returns the plan, or None when the geometry
-        is infeasible — the caller then serves single-device, and the
-        error sticks so the fallback is decided once, not per request."""
+        database staging. A plan that cannot be built raises: a mesh
+        the caller asked for is never silently replaced by one device.
+        Returns None only after a device OOM demoted the mesh (counted
+        in `pir.mesh_fallbacks`)."""
         with self._mesh_lock:
             if self._mesh_plan is not None:
                 return self._mesh_plan
             if self._mesh_plan_error is not None:
                 return None
-            try:
-                plan = self._build_mesh_plan(num_keys_hint)
-                db = self._database.streaming_chunks(
-                    cut_levels=plan.cut_levels,
-                    bitmajor=plan.bitmajor,
-                    mesh=self._mesh,
-                    shard_axis=plan.shard_axis,
-                )
-            except Exception as exc:  # noqa: BLE001 - sticky fallback
-                self._mesh_plan_error = exc
-                self._note_mesh_fallback("plan", exc)
-                return None
+            plan = self._build_mesh_plan(num_keys_hint)
+            db = self._database.streaming_chunks(
+                cut_levels=plan.cut_levels,
+                bitmajor=plan.bitmajor,
+                mesh=self._mesh,
+                shard_axis=plan.shard_axis,
+            )
             self._mesh_plan = plan
             self._mesh_db = db
             return plan
@@ -889,27 +862,27 @@ class DenseDpfPirServer(DpfPirServer):
             ip=streaming_ip(jax.default_backend()),
         )
 
-    def _note_mesh_fallback(self, stage: str, exc: BaseException) -> None:
+    def _note_mesh_oom(self, exc: BaseException) -> None:
         import warnings
 
         tracing.runtime_counters.inc("pir.mesh_fallbacks")
         events_mod.emit(
             "pir.mesh_fallback",
-            f"mesh serving disabled after {stage} failure; serving "
+            "mesh serving disabled after a device OOM; serving "
             "single-device",
             severity="warning",
-            stage=stage,
+            stage="dispatch",
             error=str(exc).splitlines()[0][:200],
         )
         warnings.warn(
-            f"mesh serving {stage} failed; falling back to single-device "
+            "mesh serving hit a device OOM; serving single-device "
             f"({str(exc).splitlines()[0][:200]})"
         )
 
     def _serve_mesh(self, keys, telemetry, seen):
         """One batch through the 2-D mesh plan. Returns the response
-        list, or None to fall back to single-device (infeasible
-        geometry, or a device OOM that permanently demotes the mesh)."""
+        list, or None to serve single-device after a device OOM that
+        permanently demoted the mesh."""
         plan = self._ensure_mesh_plan(len(keys))
         if plan is None:
             return None
@@ -958,7 +931,7 @@ class DenseDpfPirServer(DpfPirServer):
                     self._mesh_plan = None
                     self._mesh_db = None
                     self._mesh_plan_error = exc
-                self._note_mesh_fallback("dispatch", exc)
+                self._note_mesh_oom(exc)
                 return None
         return words_to_record_bytes(
             out, len(keys), self._database.max_value_size

@@ -201,10 +201,17 @@ _EVAL_GENERATION: contextvars.ContextVar = contextvars.ContextVar(
 
 
 # ---------------------------------------------------------------------------
-# Persistent JAX compilation cache (opt-in, process-wide)
+# Persistent JAX compilation cache (process-wide)
 # ---------------------------------------------------------------------------
 
-_COMPILE_CACHE_ENV = "DPF_TPU_COMPILE_CACHE_DIR"
+# The fixed fallback location: the cache key includes the path, so a
+# directory that moves between runs never hits.
+REPO_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
 _compile_cache_state: Optional[dict] = None
 _compile_cache_lock = threading.Lock()
 
@@ -216,57 +223,44 @@ def _cache_entries(path: str) -> int:
         return 0
 
 
-def install_compile_cache() -> Optional[dict]:
-    """Opt-in persistent JAX compilation cache: when
-    `DPF_TPU_COMPILE_CACHE_DIR` is set, point
-    `jax_compilation_cache_dir` at it so a restarted process deserializes
-    yesterday's XLA programs instead of recompiling them on the first
-    request. Idempotent and process-wide (the cache is a JAX global);
-    returns the state dict (None when the env is unset). The state —
-    cache dir, entries present at startup (warm), and entries persisted
-    by this process (cold compiles now cached for the next restart) —
-    is pushed into the device telemetry so `/statusz`'s compile table
-    shows it next to the per-site compile counts."""
+def install_compile_cache() -> dict:
+    """The one compile-cache rule for sessions, `chip_smoke.py` and
+    `bench.py`: when `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it
+    itself and nothing is set here; otherwise the cache goes to the
+    fixed `<repo>/.jax_cache`, persisting every program (serving's jit
+    shapes are few and bucketed, and the cold first request is what the
+    cache exists to kill). Idempotent and process-wide; returns the
+    state dict — cache dir, entries present at startup, and entries
+    persisted by this process — which is also pushed into the device
+    telemetry so `/statusz`'s compile table shows it."""
     global _compile_cache_state
+    import jax
+
     with _compile_cache_lock:
         if _compile_cache_state is not None:
             return _compile_cache_state
-        path = os.environ.get(_COMPILE_CACHE_ENV, "").strip()
+        path = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
         if not path:
-            return None
-        try:
+            path = REPO_COMPILE_CACHE_DIR
             os.makedirs(path, exist_ok=True)
-            import jax
-
             jax.config.update("jax_compilation_cache_dir", path)
-            # Persist every program: serving's jit shapes are few and
-            # bucketed, and the cold first request is exactly what the
-            # cache exists to kill. Older jaxlibs lack the thresholds.
             for knob, value in (
                 ("jax_persistent_cache_min_compile_time_secs", 0.0),
                 ("jax_persistent_cache_min_entry_size_bytes", -1),
             ):
-                try:
-                    jax.config.update(knob, value)
-                except Exception:  # noqa: BLE001 - knob absent is fine
-                    pass
-            state = {
-                "dir": path,
-                "warm_entries_at_start": _cache_entries(path),
-            }
-        except Exception as e:  # noqa: BLE001 - cache is an optimization
-            state = {"dir": path, "error": f"{type(e).__name__}: {e}"}
+                jax.config.update(knob, value)
+        state = {"dir": path, "warm_entries_at_start": _cache_entries(path)}
         _compile_cache_state = state
 
     def _info() -> dict:
-        out = dict(state)
-        if "error" not in out:
-            current = _cache_entries(path)
-            out["entries"] = current
-            out["persisted_this_process"] = max(
+        current = _cache_entries(path)
+        return dict(
+            state,
+            entries=current,
+            persisted_this_process=max(
                 0, current - state["warm_entries_at_start"]
-            )
-        return out
+            ),
+        )
 
     default_telemetry().set_compile_cache_info(_info)
     return state
@@ -288,7 +282,7 @@ class _Session:
         self._name = name
         # Opt-in persistent compilation cache first: it must be wired
         # before the session's first jit dispatch to serve warm
-        # programs (no-op without DPF_TPU_COMPILE_CACHE_DIR).
+        # programs.
         install_compile_cache()
         # Device telemetry rides the session's registry: compile events
         # and HBM watermarks from the dispatch sites below show up on

@@ -167,3 +167,29 @@ def test_native_keygen_batch_matches_numpy(monkeypatch):
             assert ca.seed == cb.seed
             assert ca.control_left == cb.control_left
             assert ca.control_right == cb.control_right
+
+
+@pytest.mark.parametrize(
+    "lib_age,source_age,stale",
+    [(None, 10, True), (5, 10, False), (20, 10, True)],
+)
+def test_native_library_rebuilds_when_missing_or_older(
+    tmp_path, monkeypatch, lib_age, source_age, stale
+):
+    """The untracked library is rebuilt from `native/` whenever it is
+    absent or older than any source file there."""
+    import os
+    import time
+
+    now = time.time()
+    src = tmp_path / "dpf_kernels.cc"
+    src.write_text("// source")
+    os.utime(src, (now - source_age, now - source_age))
+    lib = tmp_path / "libdpf_native.so"
+    if lib_age is not None:
+        lib.write_bytes(b"\x7fELF")
+        os.utime(lib, (now - lib_age, now - lib_age))
+    (tmp_path / ".build.lock").write_text("")
+    monkeypatch.setattr(native, "_NATIVE_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_LIB_PATH", str(lib))
+    assert native._stale() is stale

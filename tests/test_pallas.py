@@ -212,9 +212,12 @@ def test_pallas_v2_rejects_tiny_group_count():
     assert out.shape == (2, 4)
 
 
-def test_database_tier_chain_fallthrough(monkeypatch):
-    """Auto mode falls through failing tiers and serves; forced tiers
-    propagate errors; remembered failures skip retries."""
+def test_database_auto_tier_raises_instead_of_falling_back(monkeypatch):
+    """Auto mode on TPU serves pallas2 alone: a failing kernel raises
+    and no slower tier answers in its place; forced tiers still serve
+    and an unknown forced tier raises."""
+    import warnings
+
     import jax
 
     from distributed_point_functions_tpu.pir import database as db_mod
@@ -230,26 +233,26 @@ def test_database_tier_chain_fallthrough(monkeypatch):
 
     monkeypatch.setenv("DPF_TPU_INNER_PRODUCT", "jnp")
     want = db.inner_product_with(sel)
+    monkeypatch.setenv("DPF_TPU_INNER_PRODUCT", "bitplane")
+    assert db.inner_product_with(sel) == want
 
-    # Forced unknown tier raises.
     monkeypatch.setenv("DPF_TPU_INNER_PRODUCT", "nope")
     with pytest.raises(ValueError, match="unknown"):
         db.inner_product_with(sel)
 
-    # Auto on a fake-TPU backend: break pallas2 + pallas, bitplane serves.
-    db2 = DenseDpfPirDatabase(records)
     monkeypatch.setenv("DPF_TPU_INNER_PRODUCT", "auto")
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert db_mod.inner_product_tier() == "pallas2"
 
     def boom(*a, **k):
         raise RuntimeError("mosaic says no")
 
     monkeypatch.setattr(db_mod, "xor_inner_product_pallas2_staged", boom)
-    monkeypatch.setattr(db_mod, "xor_inner_product_pallas_staged", boom)
-    with pytest.warns(UserWarning):
-        got = db2.inner_product_with(sel)
-    assert got == want
-    assert db2._failed_tiers == {"pallas2", "pallas"}
+    monkeypatch.setattr(db_mod, "xor_inner_product", boom)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        with pytest.raises(RuntimeError, match="mosaic says no"):
+            DenseDpfPirDatabase(records).inner_product_with(sel)
 
 
 def test_pallas_v2_wide_records_cap_query_tile():

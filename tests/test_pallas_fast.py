@@ -214,35 +214,43 @@ def test_head_kernel_tiny():
     np.testing.assert_array_equal(np.asarray(got_c), np.asarray(want_c))
 
 
-def test_head_split_policy():
-    """_head_split: honors the env override even unverified (forced A/B
-    legs), requires verification in auto, caps by VMEM lanes, and never
-    returns a 1-level head."""
+def test_head_split_policy(monkeypatch):
+    """_head_split: DPF_TPU_HEAD_LEVELS forces the count (clamped to the
+    available levels); otherwise only walk mode has a head, filled to
+    the 2048-lane cap (kg=4 -> 9 levels, at least 2 levels)."""
     from distributed_point_functions_tpu.pir import dense_eval_planes as dep
 
-    # Unverified auto -> no head.
     assert dep._head_split(4, 13) == 0
-    # Explicit env override works unverified (clamped to a_levels).
-    import os
+    assert dep._head_split(4, 13, walk=True) == 9
+    assert dep._head_split(4, 2, walk=True) == 2
+    assert dep._head_split(2048, 5, walk=True) == 0  # cap below 2 levels
+    monkeypatch.setenv("DPF_TPU_HEAD_LEVELS", "6")
+    assert dep._head_split(4, 13) == 6
+    assert dep._head_split(4, 3) == 3
 
-    os.environ["DPF_TPU_HEAD_LEVELS"] = "6"
-    try:
-        assert dep._head_split(4, 13) == 6
-        assert dep._head_split(4, 3) == 3
-    finally:
-        del os.environ["DPF_TPU_HEAD_LEVELS"]
-    # Verified auto: fill until the 2048-lane cap (kg=4 -> 9 levels).
-    old_v, old_f = dep._HEAD_KERNEL_VERIFIED, dep._HEAD_KERNEL_FAILED
-    dep._HEAD_KERNEL_VERIFIED, dep._HEAD_KERNEL_FAILED = True, False
-    try:
-        assert dep._head_split(4, 13) == 9
-        assert dep._head_split(4, 2) == 2
-        assert dep._head_split(2048, 5) == 0  # cap below 2 levels
-        # A remembered failure disables the auto head.
-        dep._HEAD_KERNEL_FAILED = True
-        assert dep._head_split(4, 13) == 0
-    finally:
-        dep._HEAD_KERNEL_VERIFIED, dep._HEAD_KERNEL_FAILED = old_v, old_f
+
+@pytest.mark.parametrize(
+    "env,backend,want",
+    [
+        ("auto", "cpu", False),
+        ("auto", "tpu", "pallas"),
+        ("xla", "tpu", False),
+        ("pallas", "cpu", "pallas"),
+        ("tail", "cpu", "tail"),
+        ("walk", "tpu", "walk"),
+    ],
+)
+def test_level_kernel_mode_resolution(monkeypatch, env, backend, want):
+    """Auto mode serves the fixed TPU kernel set on TPU and the XLA
+    levels elsewhere; forced modes are taken as given."""
+    from distributed_point_functions_tpu.pir import dense_eval_planes as dep
+
+    monkeypatch.setenv("DPF_TPU_LEVEL_KERNEL", env)
+    monkeypatch.setattr(dep.jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(dep, "_LEVEL_KERNEL_VERIFIED", True)
+    assert dep._level_kernel_enabled() == want
+    if want == "pallas" and env == "auto":
+        assert want == dep.TPU_AUTO_LEVEL_KERNEL
 
 
 @pytest.mark.parametrize("num_words", [2, 16])
@@ -268,25 +276,6 @@ def test_ip_v2_tiny(num_words):
     )
     np.testing.assert_array_equal(got, xor_inner_product_np(db, sel))
 
-
-def test_tail_failure_demotes_tail_mode(monkeypatch):
-    """A remembered tail failure must demote tail mode everywhere —
-    FAILED wins over a stale VERIFIED flag, in both the eager self-check
-    and the traced-context branch (ADVICE r03 medium)."""
-    from distributed_point_functions_tpu.pir import dense_eval_planes as dep
-
-    monkeypatch.setattr(dep, "_TAIL_KERNEL_VERIFIED", True)
-    monkeypatch.setattr(dep, "_TAIL_KERNEL_FAILED", True)
-    assert dep._tail_kernel_selfcheck() is False
-
-    monkeypatch.setenv("DPF_TPU_LEVEL_KERNEL", "auto")
-    monkeypatch.setattr(dep.jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(dep, "_LEVEL_KERNEL_FAILED", False)
-    monkeypatch.setattr(dep, "_LEVEL_KERNEL_VERIFIED", True)
-    monkeypatch.setattr(dep, "_trace_state_clean", lambda: False)
-    assert dep._level_kernel_enabled() == "pallas"
-    monkeypatch.setattr(dep, "_TAIL_KERNEL_FAILED", False)
-    assert dep._level_kernel_enabled() == "tail"
 
 
 @pytest.mark.parametrize(
@@ -360,51 +349,3 @@ def test_walk_descend_kernel_tiny(value_hash, unroll):
     )
     np.testing.assert_array_equal(np.asarray(got_s), want_s)
     np.testing.assert_array_equal(np.asarray(got_c), want_c)
-
-
-def test_kernel_verdict_cache_roundtrip(tmp_path, monkeypatch):
-    """A recorded Mosaic failure verdict must be re-applied in a fresh
-    process (simulated by resetting the flags + the loaded marker):
-    re-attempting a known-failing kernel compile costs minutes of
-    remote-compile on hardware, which the persistent cache exists to
-    skip."""
-    from distributed_point_functions_tpu.pir import dense_eval_planes as dep
-
-    cache = tmp_path / "verdicts.json"
-    monkeypatch.setenv("DPF_TPU_VERDICT_CACHE", str(cache))
-    monkeypatch.setattr(dep, "_LAST_RECORDED", None)
-    monkeypatch.setattr(dep, "_TAIL_KERNEL_FAILED", True)
-    monkeypatch.setattr(dep, "_HEAD_KERNEL_VERIFIED", True)
-    monkeypatch.setattr(dep, "_LEVEL_KERNEL_VERIFIED", True)
-    dep.record_kernel_verdicts()
-    assert cache.exists()
-
-    # "Fresh process": all flags cleared, loader not yet run.
-    for flag in dep._VERDICT_FLAGS:
-        monkeypatch.setattr(dep, flag, False)
-    monkeypatch.setattr(dep, "_VERDICTS_LOADED", False)
-    dep._load_kernel_verdicts()
-    assert dep._TAIL_KERNEL_FAILED is True
-    assert dep._HEAD_KERNEL_VERIFIED is True
-    assert dep._LEVEL_KERNEL_VERIFIED is True
-    # Never-set flags stay clear.
-    assert dep._LEVEL_KERNEL_FAILED is False
-    assert dep._HEAD_KERNEL_FAILED is False
-
-    # Suspended recording must not leak speculative flags to disk.
-    monkeypatch.setattr(dep, "_WALK_KERNEL_FAILED", True)
-    with dep.suspend_verdict_recording():
-        dep.record_kernel_verdicts()
-    monkeypatch.setattr(dep, "_WALK_KERNEL_FAILED", False)
-    monkeypatch.setattr(dep, "_VERDICTS_LOADED", False)
-    dep._load_kernel_verdicts()
-    assert dep._WALK_KERNEL_FAILED is False
-
-    # A second record merges (does not clear) earlier verdicts.
-    monkeypatch.setattr(dep, "_HEAD_KERNEL_FAILED", True)
-    dep.record_kernel_verdicts()
-    monkeypatch.setattr(dep, "_VERDICTS_LOADED", False)
-    monkeypatch.setattr(dep, "_TAIL_KERNEL_FAILED", False)
-    dep._load_kernel_verdicts()
-    assert dep._TAIL_KERNEL_FAILED is True
-    assert dep._HEAD_KERNEL_FAILED is True

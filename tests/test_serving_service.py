@@ -5,6 +5,7 @@ the protocol mechanics (hybrid encryption, OTP masking, share
 combination) are the real ones from pir/ and crypto/.
 """
 
+import os
 import threading
 import time
 
@@ -228,3 +229,33 @@ def test_helper_permanently_down_degraded_mode_keeps_answering():
     assert masked[0] != RECORDS[11]
     assert counters["leader.degraded_responses"] == 1
     assert counters["leader.helper_failures"] == 1
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_compile_cache_rule(tmp_path, monkeypatch, env_dir):
+    """One rule: JAX_COMPILATION_CACHE_DIR when set (JAX reads it; the
+    helper sets nothing), else the fixed <repo>/.jax_cache."""
+    import jax
+
+    from distributed_point_functions_tpu.serving import service
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setattr(service, "_compile_cache_state", None)
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        state = service.install_compile_cache()
+        if env_dir:
+            assert state["dir"] == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(
+                __file__
+            )))
+            want = os.path.join(repo, ".jax_cache")
+            assert state["dir"] == want == service.REPO_COMPILE_CACHE_DIR
+            assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

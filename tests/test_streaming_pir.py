@@ -7,7 +7,6 @@ the chunk-sharded mesh variant, the hierarchical-geometry tail-kernel
 verdict, and the database staging locks. All CPU-runnable (tier-1).
 """
 
-import functools
 import threading
 
 import jax
@@ -364,31 +363,33 @@ def test_streaming_disabled_falls_back_to_chunked(monkeypatch):
     assert got == want
 
 
-def test_streaming_ip_failure_demotes_to_jnp(monkeypatch):
-    """A crash in the pallas2 scan tier demotes to the jnp tier for the
-    process (one warning), still answering correctly."""
+def test_streaming_ip_failure_raises(monkeypatch):
+    """A crash in the pallas2 scan tier raises out of serving: the jnp
+    scan never answers in its place, and nothing is demoted."""
+    import warnings
+
+    from distributed_point_functions_tpu.observability import tracing
+
     num_records = 1000
     records = [RNG.bytes(8) for _ in range(num_records)]
-    plain = DenseDpfPirServer.create_plain(DenseDpfPirDatabase(records))
     server = DenseDpfPirServer.create_plain(DenseDpfPirDatabase(records))
-    indices = [5, 999]
-    _, _, _, keys0, _ = _staged_batch(num_records, indices)
+    _, _, _, keys0, _ = _staged_batch(num_records, [5, 999])
     req = messages.PirRequest(
         plain_request=messages.PlainRequest(dpf_keys=list(keys0))
     )
-    want = plain.handle_plain_request(req).dpf_pir_response.masked_response
-
     monkeypatch.setenv("DPF_TPU_SELECTION_BYTES_BUDGET", "64")
     monkeypatch.setenv("DPF_TPU_STREAMING_IP", "pallas2")
-    # pallas2's compiled path raises on CPU long before Mosaic; the
-    # demotion contract is the same as a TPU compile crash.
-    with pytest.warns(UserWarning, match="falling back"):
-        got = server.handle_plain_request(req).dpf_pir_response.masked_response
-    assert got == want
-    assert server._streaming_ip_failed is True
-    # Second batch goes straight to jnp: no second warning.
-    got2 = server.handle_plain_request(req).dpf_pir_response.masked_response
-    assert got2 == want
+    before = tracing.runtime_counters.get("pir.tier_demotions")
+    # pallas2's compiled path raises on CPU long before Mosaic — the
+    # same contract as a TPU compile crash.
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        with pytest.raises(Exception) as info:
+            server.handle_plain_request(req)
+    assert not isinstance(info.value, Warning)
+    assert not [w for w in caught if "fall" in str(w.message)]
+    assert server._plan_serving(2, False).ip == "pallas2"
+    assert tracing.runtime_counters.get("pir.tier_demotions") == before
 
 
 # ---------------------------------------------------------------------------
@@ -424,64 +425,26 @@ def test_sharded_streaming_matches_oracle():
 
 
 # ---------------------------------------------------------------------------
-# Hierarchical-geometry tail verdict (dpf.py's walk fallback)
+# Hierarchical expansion dispatch (dpf.py)
 # ---------------------------------------------------------------------------
 
 
-def test_tail_hier_selfcheck_and_gate(monkeypatch):
-    from distributed_point_functions_tpu.pir import dense_eval_planes as dep
-
-    monkeypatch.setattr(
-        dep, "expand_tail_planes_pallas",
-        functools.partial(dep.expand_tail_planes_pallas, interpret=True),
-    )
-    for flag in ("_TAIL_HIER_VERIFIED", "_TAIL_HIER_FAILED"):
-        monkeypatch.setattr(dep, flag, False)
-    assert dep._tail_hier_selfcheck() is True
-    assert dep._TAIL_HIER_VERIFIED is True
-    assert dep._tail_hier_ok() is True
-    status = dep.level_kernel_status()
-    assert status["tail_hier_verified"] is True
-    assert status["tail_hier_failed"] is False
-
-    # Under an active trace only a prior eager verification counts.
-    monkeypatch.setattr(dep, "_trace_state_clean", lambda: False)
-    assert dep._tail_hier_ok() is True
-    monkeypatch.setattr(dep, "_TAIL_HIER_VERIFIED", False)
-    assert dep._tail_hier_ok() is False
-
-
-def test_tail_hier_failure_is_isolated(monkeypatch):
-    """A hier-geometry tail miscompile demotes ONLY that geometry: the
-    dense-tile tail verdict keeps serving the concat tail."""
-    from distributed_point_functions_tpu.pir import dense_eval_planes as dep
-
-    for flag in ("_TAIL_HIER_VERIFIED", "_TAIL_HIER_FAILED"):
-        monkeypatch.setattr(dep, flag, False)
-    monkeypatch.setattr(dep, "_TAIL_KERNEL_VERIFIED", True)
-    monkeypatch.setattr(dep, "_TAIL_KERNEL_FAILED", False)
-
-    def boom(*a, **k):
-        raise RuntimeError("mosaic hier tail says no")
-
-    monkeypatch.setattr(dep, "expand_tail_planes_pallas", boom)
-    with pytest.warns(UserWarning, match="hierarchical-geometry"):
-        assert dep._tail_hier_ok() is False
-    assert dep._TAIL_HIER_FAILED is True
-    assert dep._TAIL_KERNEL_VERIFIED is True
-    assert dep._TAIL_KERNEL_FAILED is False
-
-
-def test_dpf_walk_fallback_gates_on_tail_hier(monkeypatch):
-    """dpf's hierarchical-walk fallback must consult the hier-geometry
-    tail verdict, not the dense-tile `_TAIL_KERNEL_VERIFIED` flag."""
+@pytest.mark.parametrize(
+    "mode,tail_kind,has_tail,head_req",
+    [("walk", "walk", True, -1), ("tail", None, True, 0),
+     ("pallas", None, False, 0)],
+)
+def test_dpf_expand_dispatch_follows_mode(
+    monkeypatch, mode, tail_kind, has_tail, head_req
+):
+    """dpf's fused expansion takes the level-kernel mode as given: walk
+    runs the walk kernels with an auto head, tail adds the fused tail,
+    pallas runs the per-level kernels alone — no geometry gate swaps
+    one for another."""
     from distributed_point_functions_tpu import dpf as dpf_mod
-    from distributed_point_functions_tpu.pir import dense_eval_planes as dep
 
     monkeypatch.setenv("DPF_TPU_EXPAND_LEVELS", "planes")
-    monkeypatch.setenv("DPF_TPU_LEVEL_KERNEL", "walk")
-    monkeypatch.setattr(dep, "_walk_hier_ok", lambda: False)
-
+    monkeypatch.setenv("DPF_TPU_LEVEL_KERNEL", mode)
     captured = {}
 
     def fake_planes_fn(num_levels, **kwargs):
@@ -489,21 +452,11 @@ def test_dpf_walk_fallback_gates_on_tail_hier(monkeypatch):
         return lambda *a: None
 
     monkeypatch.setattr(dpf_mod, "_expand_levels_planes_fn", fake_planes_fn)
-
-    # Old behavior trusted the dense-tile verdict; the hier verdict must
-    # now say no -> per-level tiers (no tail program).
-    monkeypatch.setattr(dep, "_TAIL_KERNEL_VERIFIED", True)
-    monkeypatch.setattr(dep, "_TAIL_KERNEL_FAILED", False)
-    monkeypatch.setattr(dep, "_tail_hier_ok", lambda: False)
     dpf_mod._expand_levels_fn(4, hash_leaves=True)
-    assert captured["tail_req"] == 0
-
-    # And the hier verdict alone is sufficient.
-    captured.clear()
-    monkeypatch.setattr(dep, "_TAIL_KERNEL_VERIFIED", False)
-    monkeypatch.setattr(dep, "_tail_hier_ok", lambda: True)
-    dpf_mod._expand_levels_fn(4, hash_leaves=True)
-    assert captured["tail_req"] > 0
+    assert captured["level_kernel"] is True
+    assert captured.get("tail_kind") == tail_kind
+    assert (captured["tail_req"] > 0) == has_tail
+    assert captured["head_req"] == head_req
 
 
 # ---------------------------------------------------------------------------
