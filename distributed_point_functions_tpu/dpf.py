@@ -37,8 +37,10 @@ import numpy as np
 from jax import lax
 
 from . import keys as fixed_keys
+from .observability import tracing
 from .observability.device import default_telemetry
 from .ops import aes, limb
+from .utils.profiling import scoped
 from .value_types import ValueType
 
 U32 = jnp.uint32
@@ -421,6 +423,7 @@ def _expand_levels_limb_fn(num_levels: int, hash_leaves: bool = False):
     fuses `HashExpandedSeeds` into the same program)."""
 
     @jax.jit
+    @scoped("dpf.expand")
     def run(seeds, control, cw_seeds, cw_left, cw_right):
         for i in range(num_levels):
             seeds, control = _expand_level_body(
@@ -456,6 +459,7 @@ def _expand_levels_planes_fn(num_levels: int, level_kernel: bool = False,
     types)."""
 
     @jax.jit
+    @scoped("dpf.expand")
     def run(seeds, control, cw_seeds, cw_left, cw_right):
         from .ops.aes_bitslice import (
             broadcast_cw_planes,
@@ -909,6 +913,7 @@ def _value_hash(seeds, num_blocks):
     jax.jit,
     static_argnames=("vtype", "cepb", "num_blocks", "party", "pre_hashed"),
 )
+@scoped("dpf.leaf_values")
 def _leaf_stage(seeds, control, vc_dev, vtype, cepb, num_blocks, party,
                 pre_hashed=False):
     """Hash seeds into value blocks, parse, apply value correction.
@@ -1469,7 +1474,7 @@ class DistributedPointFunction:
 
     def _expand(self, seeds: jnp.ndarray, control: jnp.ndarray,
                 key: DpfKey, start: int, stop: int,
-                hash_leaves: bool = False):
+                hash_leaves: bool = False, staged_cw=None):
         """Expand seeds from tree level `start` to `stop` (width-doubling).
 
         All levels run in ONE jitted program (specialized per level count
@@ -1477,7 +1482,9 @@ class DistributedPointFunction:
         jits would pay one dispatch per level and a fresh compile per
         distinct width. With `hash_leaves` the returned seeds are already
         value-hashed (fused `HashExpandedSeeds`; single-block value types
-        only — the leaf stage then skips its own hash).
+        only — the leaf stage then skips its own hash). `staged_cw` is
+        `_device_correction_words(key, start, stop)` when the caller has
+        already placed them.
         """
         if stop - start > 62:
             raise ValueError(
@@ -1490,15 +1497,17 @@ class DistributedPointFunction:
                     aes.mmo_hash(fixed_keys.RK_VALUE, seeds), control
                 )
             return seeds, control
-        cw_seeds, cw_left, cw_right = self._stage_correction_words(
-            key, start, stop
-        )
+        if staged_cw is None:
+            staged_cw = self._device_correction_words(key, start, stop)
         return _expand_levels_fn(stop - start, hash_leaves=hash_leaves)(
-            seeds,
-            control,
-            jnp.asarray(cw_seeds),
-            jnp.asarray(cw_left),
-            jnp.asarray(cw_right),
+            seeds, control, *staged_cw
+        )
+
+    def _device_correction_words(self, key: DpfKey, start: int, stop: int):
+        """Levels [start, stop) of `key`'s correction words, on device."""
+        return tuple(
+            jnp.asarray(x)
+            for x in self._stage_correction_words(key, start, stop)
         )
 
     def _walk_paths(self, seeds, control, paths_np, key_or_keys, start: int,
@@ -1575,9 +1584,10 @@ class DistributedPointFunction:
         level whose subtrees to expand. Mirrors `EvaluateNext`
         (`distributed_point_function.h:319-333`).
         """
-        return self.evaluate_until(
-            ctx.previous_hierarchy_level + 1, prefixes, ctx
-        )
+        with tracing.span("dpf.evaluate_next", record=False):
+            return self.evaluate_until(
+                ctx.previous_hierarchy_level + 1, prefixes, ctx
+            )
 
     def evaluate_until(self, hierarchy_level: int, prefixes: Sequence[int],
                        ctx: EvaluationContext):
@@ -1616,17 +1626,27 @@ class DistributedPointFunction:
 
         stop_level = self._hierarchy_to_tree[hierarchy_level]
         if not prefixes:
-            seeds = jnp.asarray(aes.u128_to_limbs(key.seed))[None, :]
-            control = jnp.asarray(
-                np.array([key.party], dtype=np.uint32)
-            )
+            # Host spans of a full-domain evaluation (dispatch only: the
+            # device runs the programs after these return).
+            with tracing.span("dpf.stage_key", record=False):
+                seeds = jnp.asarray(aes.u128_to_limbs(key.seed))[None, :]
+                control = jnp.asarray(
+                    np.array([key.party], dtype=np.uint32)
+                )
+                staged_cw = (
+                    self._device_correction_words(key, 0, stop_level)
+                    if stop_level > 0 else None
+                )
             fuse = self._blocks_needed[hierarchy_level] == 1
-            seeds, control = self._expand(
-                seeds, control, key, 0, stop_level, hash_leaves=fuse
-            )
-            out = self._leaf_values(
-                seeds, control, key, hierarchy_level, pre_hashed=fuse
-            )
+            with tracing.span("dpf.expand", record=False):
+                seeds, control = self._expand(
+                    seeds, control, key, 0, stop_level, hash_leaves=fuse,
+                    staged_cw=staged_cw,
+                )
+            with tracing.span("dpf.leaf_values", record=False):
+                out = self._leaf_values(
+                    seeds, control, key, hierarchy_level, pre_hashed=fuse
+                )
             ctx.previous_hierarchy_level = hierarchy_level
             return out
 

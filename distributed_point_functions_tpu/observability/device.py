@@ -90,6 +90,16 @@ def shape_key(*parts) -> str:
     return key
 
 
+class _Dispatch:
+    """What `CompileTracker.dispatch` yields: where the caller hands in
+    the timing of a span over the same call."""
+
+    __slots__ = ("timing",)
+
+    def __init__(self):
+        self.timing = None
+
+
 class CompileTracker:
     """Per-site compile/dispatch accounting keyed by shape signature.
 
@@ -173,7 +183,10 @@ class CompileTracker:
     def dispatch(self, site: str, key: str):
         """Bracket one dispatch: times the call, and attributes the
         wall time as compile latency iff the shape is new (first call
-        through a jit entry point includes trace+compile).
+        through a jit entry point includes trace+compile). A caller that
+        times the same call in a span sets the yielded handle's `timing`
+        (a `tracing.SpanTiming` closed inside this bracket): that one
+        timing is then used.
 
         Chaos site `device.dispatch.<site>`: armed with action="oom"
         it raises `SimulatedResourceExhausted` at dispatch, standing
@@ -181,11 +194,16 @@ class CompileTracker:
         `pir/server.py`'s runtime tier demotion is tested against.
         """
         failpoints.fire(f"device.dispatch.{site}")
-        t0 = time.perf_counter()
+        handle = _Dispatch()
+        t0 = time.perf_counter_ns()
         try:
-            yield
+            yield handle
         finally:
-            elapsed_ms = (time.perf_counter() - t0) * 1e3
+            timing = handle.timing
+            elapsed_ms = (
+                timing.ms if timing is not None and timing.end_ns is not None
+                else (time.perf_counter_ns() - t0) / 1e6
+            )
             # Record after the call so the compile histogram sees the
             # real first-call latency, not a guess made on entry.
             with self._lock:

@@ -39,7 +39,9 @@ Mechanics mirror `tracing.py` deliberately:
 * a contextvar carries the active `RequestPhases`; `phase("name")`
   brackets attribute *exclusive* time (nested brackets subtract their
   elapsed from the parent, so a staging bracket inside a compute
-  bracket does not double-count);
+  bracket does not double-count); `phase("name", span="stage")` is a
+  bracket and a tracing span over one interval, timed once, and while
+  the span log records a bare bracket is logged as `phase.<name>`;
 * the record crosses threads **by reference** — the batcher worker
   captures `current_request()` at submit time and calls
   `RequestPhases.add` from its own thread (same pattern as grafting
@@ -112,14 +114,19 @@ class RequestPhases:
     into the next aggregate window."""
 
     __slots__ = (
-        "role", "_t0", "_phases", "_stack", "_closed", "_lock", "_meta",
+        "role", "_t0", "end_ns", "_phases", "_stack", "_closed", "_lock",
+        "_meta",
     )
 
-    def __init__(self, role: str):
+    def __init__(self, role: str, start_ns: Optional[int] = None):
         self.role = role
-        self._t0 = time.perf_counter()
+        # perf_counter_ns: the span log's clock. A caller that times the
+        # same interval hands its start in (and its end via `end_ns`).
+        self._t0 = time.perf_counter_ns() if start_ns is None else start_ns
+        self.end_ns: Optional[int] = None
         self._phases: Dict[str, float] = {}
-        # Active bracket stack (this thread only): [name, t0, child_ms].
+        # Active bracket stack (this thread only): [name, t0_ns,
+        # child_ms].
         self._stack: list = []
         self._closed = False
         self._lock = threading.Lock()
@@ -151,14 +158,18 @@ class RequestPhases:
 
     # -- exclusive-time brackets (single-threaded per record) ---------------
 
-    def begin(self, name: str) -> None:
+    def begin(self, name: str, t_ns: Optional[int] = None) -> None:
+        if t_ns is None:
+            t_ns = time.perf_counter_ns()
         with self._lock:
-            self._stack.append([name, time.perf_counter(), 0.0])
+            self._stack.append([name, t_ns, 0.0])
 
-    def end(self) -> None:
+    def end(self, t_ns: Optional[int] = None) -> None:
+        if t_ns is None:
+            t_ns = time.perf_counter_ns()
         with self._lock:
             name, t0, child_ms = self._stack.pop()
-            elapsed_ms = (time.perf_counter() - t0) * 1e3
+            elapsed_ms = (t_ns - t0) / 1e6
             if self._stack:
                 self._stack[-1][2] += elapsed_ms
             if self._closed:
@@ -168,7 +179,8 @@ class RequestPhases:
                 self._phases[name] = self._phases.get(name, 0.0) + own
 
     def elapsed_ms(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e3
+        end = self.end_ns if self.end_ns is not None else time.perf_counter_ns()
+        return (end - self._t0) / 1e6
 
     def snapshot(self) -> Dict[str, float]:
         with self._lock:
@@ -192,19 +204,37 @@ def current_request() -> Optional[RequestPhases]:
 
 
 @contextlib.contextmanager
-def phase(name: str):
+def phase(name: str, span: Optional[str] = None, **attrs):
     """Bracket the enclosed block as phase `name` of the active request
-    (exclusive time — nested brackets deduct). No active record (or a
-    disabled recorder) makes this a no-op."""
+    (exclusive time — nested brackets deduct). With `span`, the block is
+    also the tracing span of that name (`attrs` are its attributes), and
+    the span's one timing feeds both. While the span log records, a
+    bracket without a span is logged as `phase.<name>`. No active record
+    (or a disabled recorder), no span and no log make this a no-op.
+    Yields the span's `tracing.SpanTiming` (None when nothing times the
+    block as a span)."""
     req = _ACTIVE.get()
-    if req is None:
-        yield
+    if span is None and not tracing.span_log.recording():
+        if req is None:
+            yield None
+            return
+        req.begin(name)
+        try:
+            yield None
+        finally:
+            req.end()
         return
-    req.begin(name)
+    timing = None
     try:
-        yield
+        with tracing.span(
+            span or f"phase.{name}", record=span is not None, **attrs
+        ) as timing:
+            if req is not None:
+                req.begin(name, timing.start_ns)
+            yield timing
     finally:
-        req.end()
+        if req is not None and timing is not None and timing.end_ns:
+            req.end(timing.end_ns)
 
 
 def record(name: str, ms: float, request: Optional[RequestPhases] = None):
@@ -259,7 +289,11 @@ class PhaseRecorder:
     # -- request lifecycle --------------------------------------------------
 
     @contextlib.contextmanager
-    def request(self, role: str, fresh: bool = False):
+    def request(self, role: str, fresh: bool = False,
+                start_ns: Optional[int] = None):
+        """`start_ns` (and the record's `end_ns`, set by the caller
+        before exit) let a caller that times the request itself share
+        that one timing with the waterfall's end-to-end total."""
         if not self.enabled:
             yield None
             return
@@ -267,7 +301,7 @@ class PhaseRecorder:
         if existing is not None and not fresh:
             yield existing
             return
-        req = RequestPhases(role)
+        req = RequestPhases(role, start_ns=start_ns)
         token = _ACTIVE.set(req)
         try:
             yield req

@@ -12,7 +12,7 @@ the id into the Helper request; the Helper's server-side spans come
 back in the response envelope and are grafted on with a `remote.`
 prefix, so helper-leg RTT splits into network vs. remote compute).
 
-Three always-on consumers, all dependency-free:
+Three always-on consumers and one on demand, all dependency-free:
 
 * the **flight recorder** keeps the N slowest and the N most recent
   errored traces (plus a short recent ring) for `/tracez` postmortems;
@@ -20,12 +20,21 @@ Three always-on consumers, all dependency-free:
   total, bounded reservoir percentiles) for bench span summaries;
 * **runtime counters** are a tiny process-global counter group for
   layers below `serving/` (the PIR planner's tier decisions) that must
-  not import the serving metrics registry.
+  not import the serving metrics registry;
+* the **span log** (`span_log`) is a bounded ring of every span, root
+  and phase bracket with absolute start and end on one clock
+  (`time.perf_counter_ns()`), its parent's name, trace id and thread:
+  the program's own timeline of a window, to lay beside a device
+  profile. It records between `span_log.start()` and `drain()`, and
+  while a profiler session captures.
 
 Everything here is stdlib + `utils/` only (`tools/check_layers.py`
 enforces serving -> observability -> utils, no pir/ops imports) and
-cheap enough for the hot path: a span is two `perf_counter()` calls,
-one tuple append, and one lock-protected stats update.
+cheap enough for the hot path: a span is two `perf_counter_ns()`
+calls, one tuple append, and one lock-protected stats update; with the
+span log off, finding it off costs one attribute check and one native
+call (the profiler's own "capturing?" flag), which also spares the
+TraceAnnotation.
 """
 
 from __future__ import annotations
@@ -39,7 +48,7 @@ import threading
 import time
 from typing import Dict, List, Optional
 
-from ..utils.profiling import annotate
+from ..utils.profiling import annotate, profiler_active
 
 __all__ = [
     "Trace",
@@ -50,6 +59,11 @@ __all__ = [
     "trace_request",
     "span",
     "add_span",
+    "log_span",
+    "SpanLog",
+    "SpanTiming",
+    "span_log",
+    "current_span",
     "default_recorder",
     "set_default_recorder",
     "stage_summary",
@@ -59,6 +73,9 @@ __all__ = [
 
 # Per-stage reservoir bound for the process-wide stage stats.
 _STAGE_RESERVOIR = 512
+# Span log ring: a whole 30 s served window (~2,900 requests x ~20
+# spans, plus ~2,000 batches) fits with room to spare.
+SPAN_LOG_CAPACITY = 1 << 17
 
 
 def new_trace_id() -> str:
@@ -73,7 +90,7 @@ class Trace:
     onto the submitting request's trace."""
 
     __slots__ = (
-        "trace_id", "name", "start_unix", "_t0", "duration_ms",
+        "trace_id", "name", "start_unix", "_t0", "end_ns", "duration_ms",
         "error", "spans", "attrs", "_lock",
     )
 
@@ -81,15 +98,21 @@ class Trace:
         self.trace_id = trace_id or new_trace_id()
         self.name = name
         self.start_unix = time.time()
-        self._t0 = time.perf_counter()
+        self._t0 = time.perf_counter_ns()
+        self.end_ns: Optional[int] = None
         self.duration_ms: Optional[float] = None
         self.error: Optional[str] = None
         self.spans: List[dict] = []
         self.attrs: Dict[str, object] = {}
         self._lock = threading.Lock()
 
+    @property
+    def start_ns(self) -> int:
+        """The root's start on the span log's clock."""
+        return self._t0
+
     def elapsed_ms(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e3
+        return (time.perf_counter_ns() - self._t0) / 1e6
 
     def add_span(
         self,
@@ -150,8 +173,13 @@ class Trace:
         with self._lock:
             return list(self.spans)
 
-    def finish(self, error: Optional[str] = None) -> "Trace":
-        self.duration_ms = round(self.elapsed_ms(), 3)
+    def finish(
+        self, error: Optional[str] = None, end_ns: Optional[int] = None
+    ) -> "Trace":
+        if end_ns is None:
+            end_ns = time.perf_counter_ns()
+        self.end_ns = end_ns
+        self.duration_ms = round((end_ns - self._t0) / 1e6, 3)
         if error is not None:
             self.error = error
         return self
@@ -248,13 +276,107 @@ def set_default_recorder(recorder: FlightRecorder) -> FlightRecorder:
     return recorder
 
 
+# ---------------------------------------------------------------------------
+# Span log: the program's timeline of a window
+# ---------------------------------------------------------------------------
+
+_LOG_FIELDS = (
+    "name", "start_ns", "end_ns", "parent", "trace_id", "thread", "attrs",
+)
+
+
+class SpanLog:
+    """Process-wide bounded ring of span records on one clock,
+    `time.perf_counter_ns()`: read beside a device profile, a window's
+    spans place every host layer on the device timeline once one mark
+    is seen on both clocks.
+
+    A record is (name, start_ns, end_ns, parent, trace_id, thread,
+    attrs): `parent` names the enclosing span on the same context (None
+    at a root; out-of-band spans pass theirs), `trace_id` is the current
+    request trace's, `thread` the closing thread's ident, `attrs` a few
+    small values (bucket, keys, party) or None.
+
+    Off by default: `start()` clears the ring and records every span
+    until `drain()`. It also records while a profiler session
+    captures, so that a profile taken by any tool comes with the
+    program's spans. When the ring is full the oldest record goes and
+    `dropped` counts it: a reader that needs a whole window treats a log
+    with drops as incomplete.
+    """
+
+    def __init__(self, capacity: int = SPAN_LOG_CAPACITY):
+        self.on = False
+        self.capacity = int(capacity)
+        self.dropped = 0
+        self.started_ns: Optional[int] = None
+        self._ring: collections.deque = collections.deque(maxlen=self.capacity)
+
+    def start(self) -> None:
+        """Clear the log and record every span from now on."""
+        self._ring = collections.deque(maxlen=self.capacity)
+        self.dropped = 0
+        self.started_ns = time.perf_counter_ns()
+        self.on = True
+
+    def recording(self) -> bool:
+        return self.on or profiler_active()
+
+    def append(self, record: tuple) -> None:
+        ring = self._ring
+        if len(ring) == ring.maxlen:
+            self.dropped += 1
+        ring.append(record)
+
+    def snapshot(self) -> dict:
+        """What the log holds, left in place: `{"started_ns", "dropped",
+        "capacity", "records"}`, each record a dict of `_LOG_FIELDS`."""
+        return {
+            "started_ns": self.started_ns,
+            "dropped": self.dropped,
+            "capacity": self.capacity,
+            "records": [dict(zip(_LOG_FIELDS, r)) for r in list(self._ring)],
+        }
+
+    def drain(self) -> dict:
+        """Stop recording and hand over what the log holds (as
+        `snapshot()`), leaving it empty."""
+        self.on = False
+        out = self.snapshot()
+        self._ring = collections.deque(maxlen=self.capacity)
+        self.dropped = 0
+        self.started_ns = None
+        return out
+
+
+span_log = SpanLog()
+
 _CURRENT: contextvars.ContextVar = contextvars.ContextVar(
     "observability_trace", default=None
+)
+# Name of the innermost logged span on this context: the parent of the
+# next record. Kept only while the log records.
+_PARENT: contextvars.ContextVar = contextvars.ContextVar(
+    "observability_span", default=None
 )
 
 
 def current_trace() -> Optional[Trace]:
     return _CURRENT.get()
+
+
+def current_span() -> Optional[str]:
+    """The innermost logged span on this context (None when the log is
+    off): what an out-of-band span recorded elsewhere names as parent."""
+    return _PARENT.get()
+
+
+def _log(name, start_ns, end_ns, parent, trace, attrs) -> None:
+    span_log.append((
+        name, start_ns, end_ns, parent,
+        trace.trace_id if trace is not None else None,
+        threading.get_ident(), attrs or None,
+    ))
 
 
 @contextlib.contextmanager
@@ -275,57 +397,133 @@ def trace_request(
     RPC boundary), so its spans must form their own server-side trace
     — sharing the caller's trace object would double-report them when
     they also travel back in the response envelope (the in-process
-    transport is the case where both sides share one thread)."""
+    transport is the case where both sides share one thread).
+
+    While the span log records, the root is logged under `name` (with
+    `trace.start_ns` and `trace.end_ns`, the one timing of the request)
+    and annotated in the active profile."""
     existing = _CURRENT.get()
     if existing is not None and not fresh:
         yield existing
         return
+    logging = span_log.on or profiler_active()
+    parent = _PARENT.get()
+    annotation = annotate(name) if logging else contextlib.nullcontext()
+    annotation.__enter__()
     trace = Trace(name, trace_id=trace_id)
     if attrs:
         trace.attrs.update(attrs)
     token = _CURRENT.set(trace)
+    parent_token = _PARENT.set(name) if logging else None
     try:
         yield trace
     except BaseException as e:
         trace.finish(error=f"{type(e).__name__}: {e}"[:300])
         raise
     finally:
+        end_ns = time.perf_counter_ns()
+        if parent_token is not None:
+            _PARENT.reset(parent_token)
         _CURRENT.reset(token)
-        trace.finish()
+        trace.finish(end_ns=end_ns)
+        if logging:
+            _log(name, trace.start_ns, end_ns, parent, trace, None)
+            annotation.__exit__(None, None, None)
         if record:
             (recorder or _DEFAULT_RECORDER).record(trace)
 
 
+class SpanTiming:
+    """The one timing of a span, handed to the enclosed block: a caller
+    that feeds another instrument (a histogram, a phase record) reads it
+    here instead of timing the interval again."""
+
+    __slots__ = ("name", "start_ns", "end_ns")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.start_ns: Optional[int] = None
+        self.end_ns: Optional[int] = None
+
+    @property
+    def ms(self) -> float:
+        return (self.end_ns - self.start_ns) / 1e6
+
+
+def _observe(name: str, dur_ms: float, attrs: dict, trace) -> None:
+    if trace is not None:
+        trace.add_span(name, dur_ms, **attrs)
+    _STAGES.observe(name, dur_ms)
+
+
 @contextlib.contextmanager
-def span(name: str, **attrs):
+def span(name: str, record: bool = True, **attrs):
     """Time the enclosed block as one stage: appended to the current
-    trace (if any), aggregated into the process-wide stage stats, and
-    nested as a TraceAnnotation inside any active xprof trace."""
-    t0 = time.perf_counter()
-    with annotate(name):
+    trace (if any) and aggregated into the process-wide stage stats
+    (`record=False` skips both). While the span log records, the span
+    is also logged, parents the spans opened inside it, and nests as a
+    TraceAnnotation in the active profile. Yields its `SpanTiming`."""
+    timing = SpanTiming(name)
+    if not (span_log.on or profiler_active()):
+        timing.start_ns = time.perf_counter_ns()
         try:
-            yield
+            yield timing
         finally:
-            dur_ms = (time.perf_counter() - t0) * 1e3
+            timing.end_ns = time.perf_counter_ns()
+            if record:
+                _observe(name, timing.ms, attrs, _CURRENT.get())
+        return
+    parent = _PARENT.get()
+    token = _PARENT.set(name)
+    with annotate(name):
+        timing.start_ns = time.perf_counter_ns()
+        try:
+            yield timing
+        finally:
+            timing.end_ns = time.perf_counter_ns()
+            _PARENT.reset(token)
             trace = _CURRENT.get()
-            if trace is not None:
-                trace.add_span(name, dur_ms, **attrs)
-            _STAGES.observe(name, dur_ms)
+            if record:
+                _observe(name, timing.ms, attrs, trace)
+            _log(name, timing.start_ns, timing.end_ns, parent, trace, attrs)
 
 
 def add_span(
     name: str,
     duration_ms: float,
     trace: Optional[Trace] = None,
+    start_ns: Optional[int] = None,
+    end_ns: Optional[int] = None,
     **attrs,
 ) -> None:
     """Out-of-band span: record an externally measured duration (e.g.
     the batcher worker timing queue wait for a request submitted on
-    another thread) onto `trace` or the current trace."""
+    another thread) onto `trace` or the current trace. While the span
+    log records it is logged too, at `start_ns`/`end_ns` on the log's
+    clock (default: ending now)."""
     trace = trace if trace is not None else _CURRENT.get()
-    if trace is not None:
-        trace.add_span(name, duration_ms, **attrs)
-    _STAGES.observe(name, duration_ms)
+    _observe(name, duration_ms, attrs, trace)
+    if span_log.on or profiler_active():
+        if end_ns is None:
+            end_ns = time.perf_counter_ns()
+        if start_ns is None:
+            start_ns = end_ns - int(duration_ms * 1e6)
+        _log(name, start_ns, end_ns, None, trace, attrs)
+
+
+def log_span(
+    name: str,
+    start_ns: int,
+    end_ns: int,
+    parent: Optional[str] = None,
+    trace: Optional[Trace] = None,
+    **attrs,
+) -> None:
+    """Log an interval timed elsewhere (explicit times on the log's
+    clock) without touching traces or stage stats: for spans whose
+    timing already feeds those instruments under another name."""
+    if span_log.on or profiler_active():
+        _log(name, start_ns, end_ns, parent, trace, attrs)
 
 
 # ---------------------------------------------------------------------------

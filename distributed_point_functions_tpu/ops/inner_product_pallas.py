@@ -48,6 +48,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils.profiling import scoped
+
 U32 = jnp.uint32
 I32 = jnp.int32
 
@@ -317,6 +319,7 @@ def _ip_kernel_v2(sel_ref, db_ref, out_ref, *, j_chunk: int, int8: bool):
     static_argnames=("tile_queries", "tile_groups", "j_chunk", "int8",
                      "interpret", "vma"),
 )
+@scoped("pir.inner_product")
 def _ip_pallas_staged_v2(
     db_perm: jnp.ndarray,
     packed: jnp.ndarray,

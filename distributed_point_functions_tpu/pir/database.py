@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..observability import tracing
 from ..observability.device import default_telemetry
 
 from ..ops.inner_product import (
@@ -971,8 +972,10 @@ class DenseDpfPirDatabase:
                 selections = jnp.pad(
                     selections, ((0, 0), (0, pad), (0, 0))
                 )
-        out = default_telemetry().transfers.to_host(
-            self._inner_product_device(selections, bitrev_blocks),
-            phase="result_readback",
-        )
+        with tracing.span("pir.inner_product", record=False):
+            answers = self._inner_product_device(selections, bitrev_blocks)
+        with tracing.span("pir.readback", record=False):
+            out = default_telemetry().transfers.to_host(
+                answers, phase="result_readback"
+            )
         return words_to_record_bytes(out, out.shape[0], self._max_value_size)

@@ -36,7 +36,9 @@ from jax import lax
 
 from .. import keys as fixed_keys
 from ..dpf import DpfKey
+from ..observability import tracing
 from ..observability.device import default_telemetry
+from ..utils.profiling import scoped
 from ..ops import aes
 
 U32 = jnp.uint32
@@ -104,7 +106,7 @@ def _evaluate_selection_blocks(
 
 evaluate_selection_blocks = functools.partial(
     jax.jit, static_argnames=("walk_levels", "expand_levels", "num_blocks")
-)(_evaluate_selection_blocks)
+)(scoped("pir.expand")(_evaluate_selection_blocks))
 
 # Donating twin for the serving hot path: the six staged key tensors
 # are freshly placed per batch (`stage_keys`) and dead after this call,
@@ -114,7 +116,7 @@ evaluate_selection_blocks = functools.partial(
 # would invalidate — so only `DenseDpfPirServer` (via
 # `donation_enabled()`) dispatches here.
 evaluate_selection_blocks_donated = jax.jit(
-    _evaluate_selection_blocks,
+    scoped("pir.expand")(_evaluate_selection_blocks),
     static_argnames=("walk_levels", "expand_levels", "num_blocks"),
     donate_argnums=(0, 1, 2, 3, 4, 5),
 )
@@ -400,19 +402,19 @@ def stage_keys(keys: Sequence[DpfKey], host_walk_levels: int = 0):
     the correction-word arrays drop the walked levels, so the device step
     runs with `walk_levels=0`.
     """
-    seeds0, control0, cw_seeds, cw_left, cw_right, last_vc = (
-        stage_keys_host(keys, host_walk_levels)
-    )
-    # One device_put for the whole staging: all six blocks are uint32,
-    # so they pack into a single flat transfer and slice back apart on
-    # device (value_types.host_const's batching note, applied). Six
-    # per-array transfers cost six dispatches on the serving hot path;
-    # the TransferLedger counts this as one h2d copy.
-    blocks = (seeds0, control0, cw_seeds, cw_left, cw_right, last_vc)
-    flat = np.concatenate([b.ravel() for b in blocks])
-    dev = default_telemetry().transfers.device_put(
-        flat, phase="key_staging"
-    )
+    with tracing.span("pir.stage_keys.host", record=False, keys=len(keys)):
+        blocks = stage_keys_host(keys, host_walk_levels)
+        # One device_put for the whole staging: all six blocks are
+        # uint32, so they pack into a single flat transfer and slice
+        # back apart on device (value_types.host_const's batching note,
+        # applied). Six per-array transfers cost six dispatches on the
+        # serving hot path; the TransferLedger counts this as one h2d
+        # copy.
+        flat = np.concatenate([b.ravel() for b in blocks])
+    with tracing.span("pir.stage_keys.put", record=False, bytes=flat.nbytes):
+        dev = default_telemetry().transfers.device_put(
+            flat, phase="key_staging"
+        )
     out = []
     offset = 0
     for b in blocks:

@@ -64,6 +64,7 @@ from ..ops.expand_planes_pallas import (
     walk_descend_planes_pallas,
 )
 from .dense_eval import _walk_zeros
+from ..utils.profiling import scoped
 
 U32 = jnp.uint32
 
@@ -518,6 +519,7 @@ def level_kernel_status() -> dict:
         "tail_kind", "head_kind", "walk_compact",
     ),
 )
+@scoped("pir.expand")
 def _evaluate_selection_blocks_planes_jit(
     seeds0: jnp.ndarray,
     control0: jnp.ndarray,

@@ -479,10 +479,13 @@ class DenseDpfPirServer(DpfPirServer):
                 ("m", "sharded"), ("q", len(keys)), ("b", self._num_blocks)
             )
             step = "device_compute" if seen("pir.plain", key) else "compile"
-            with tracing.span("evaluate_sharded", num_keys=len(keys)), \
-                    telemetry.hbm.phase("selection"), \
-                    telemetry.compile_tracker.dispatch("pir.plain", key), \
-                    phases_mod.phase(step):
+            with telemetry.hbm.phase("selection"), \
+                    telemetry.compile_tracker.dispatch(
+                        "pir.plain", key) as dispatch, \
+                    phases_mod.phase(
+                        step, span="evaluate_sharded", num_keys=len(keys)
+                    ) as timing:
+                dispatch.timing = timing
                 inner_products = self._inner_products_sharded(
                     staged, len(keys)
                 )
@@ -537,11 +540,14 @@ class DenseDpfPirServer(DpfPirServer):
             step = (
                 "device_compute" if seen("pir.plain", key) else "compile"
             )
-            with tracing.span(
-                "evaluate_streaming", num_keys=plan.num_keys, ip=plan.ip
-            ), telemetry.hbm.phase("selection"), \
-                    telemetry.compile_tracker.dispatch("pir.plain", key), \
-                    phases_mod.phase(step):
+            with telemetry.hbm.phase("selection"), \
+                    telemetry.compile_tracker.dispatch(
+                        "pir.plain", key) as dispatch, \
+                    phases_mod.phase(
+                        step, span="evaluate_streaming",
+                        num_keys=plan.num_keys, ip=plan.ip,
+                    ) as timing:
+                dispatch.timing = timing
                 return self._inner_products_streaming(plan, keys)
         if plan.mode == "chunked":
             with phases_mod.phase("h2d_transfer"):
@@ -555,10 +561,13 @@ class DenseDpfPirServer(DpfPirServer):
             step = (
                 "device_compute" if seen("pir.plain", key) else "compile"
             )
-            with tracing.span("evaluate_chunked", num_keys=plan.num_keys), \
-                    telemetry.hbm.phase("selection"), \
-                    telemetry.compile_tracker.dispatch("pir.plain", key), \
-                    phases_mod.phase(step):
+            with telemetry.hbm.phase("selection"), \
+                    telemetry.compile_tracker.dispatch(
+                        "pir.plain", key) as dispatch, \
+                    phases_mod.phase(
+                        step, span="evaluate_chunked", num_keys=plan.num_keys
+                    ) as timing:
+                dispatch.timing = timing
                 return self._inner_products_chunked(
                     staged, plan.num_keys, plan
                 )
@@ -572,11 +581,13 @@ class DenseDpfPirServer(DpfPirServer):
             ("b", self._num_blocks),
         )
         step = "device_compute" if seen("pir.plain", key) else "compile"
-        with tracing.span(
-            "evaluate_materialized", num_keys=plan.num_keys
-        ), telemetry.hbm.phase("selection"), \
-                telemetry.compile_tracker.dispatch("pir.plain", key), \
-                phases_mod.phase(step):
+        with telemetry.hbm.phase("selection"), \
+                telemetry.compile_tracker.dispatch(
+                    "pir.plain", key) as dispatch, \
+                phases_mod.phase(
+                    step, span="evaluate_materialized", num_keys=plan.num_keys
+                ) as timing:
+            dispatch.timing = timing
             # Nested bracket: staging time lands in h2d_transfer
             # and is deducted from the enclosing compute phase
             # (exclusive-time semantics).
@@ -910,20 +921,23 @@ class DenseDpfPirServer(DpfPirServer):
             )
             mesh_db = self._mesh_db
             try:
-                with tracing.span(
-                    "evaluate_mesh",
-                    num_keys=len(keys),
-                    shards=plan.num_shards,
-                    key_devices=plan.num_key_devices,
-                ), telemetry.hbm.phase("selection"), \
+                with telemetry.hbm.phase("selection"), \
                         telemetry.compile_tracker.dispatch(
                             "pir.plain", key
-                        ), \
-                        phases_mod.phase(step):
-                    out_dev = plan.run(staged, mesh_db)
-                    out = telemetry.transfers.to_host(
-                        out_dev, phase="result_readback"
-                    )
+                        ) as dispatch, \
+                        phases_mod.phase(
+                            step, span="evaluate_mesh",
+                            num_keys=len(keys),
+                            shards=plan.num_shards,
+                            key_devices=plan.num_key_devices,
+                        ) as timing:
+                    dispatch.timing = timing
+                    with tracing.span("pir.inner_product", record=False):
+                        out_dev = plan.run(staged, mesh_db)
+                    with tracing.span("pir.readback", record=False):
+                        out = telemetry.transfers.to_host(
+                            out_dev, phase="result_readback"
+                        )
             except Exception as exc:  # noqa: BLE001 - OOM-gated below
                 if not self._is_resource_exhausted(exc):
                     raise

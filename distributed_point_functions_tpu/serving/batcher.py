@@ -27,7 +27,9 @@ missing piece:
   `retry_after_s` hint on the `Overloaded`, and the queue dequeues
   across tenants in weighted-fair order instead of global FIFO.
 * Requests carry an optional absolute **deadline** (`time.monotonic()`
-  seconds). The worker drops requests that expired while the batch was
+  seconds; the batcher's own stamps of submit, batch gate and
+  evaluation are on the span log's clock, `time.perf_counter_ns()`).
+  The worker drops requests that expired while the batch was
   forming *before* any device work is dispatched — they fail with
   `DeadlineExceeded` without evaluating, and a bucket whose every
   request died skips the dispatch entirely — and the submitting thread
@@ -46,6 +48,12 @@ missing piece:
   and completion halves), and `close()` drains the in-flight stage
   before returning. Depth 1 completes inline — the pre-pipeline
   behavior, bit for bit.
+
+Spans: every request logs `batcher.queue_wait` (submit to the batch
+gate, out of band, with the party) and every batch runs inside one
+`batcher.evaluate` span (bucket, live keys, party) whose timing also
+feeds the `<name>.evaluate_ms` histogram, the compile tracker and the
+phase split: one timing for all of them.
 
 The batcher is generic over the evaluation function
 (`evaluate(keys) -> list of per-key results`), so it serves any of the
@@ -119,8 +127,9 @@ def _sync_wait_ms(telemetry) -> float:
 
 class _Pending:
     __slots__ = (
-        "keys", "deadline", "event", "result", "error", "t0", "abandoned",
-        "trace", "phases", "tenant", "cost", "generation",
+        "keys", "deadline", "event", "result", "error", "t0_ns",
+        "abandoned", "trace", "parent", "phases", "tenant", "cost",
+        "generation",
     )
 
     def __init__(self, keys, deadline, tenant="default", cost=None):
@@ -131,7 +140,7 @@ class _Pending:
         self.event = threading.Event()
         self.result = None
         self.error: Optional[BaseException] = None
-        self.t0 = time.monotonic()
+        self.t0_ns = time.perf_counter_ns()
         self.abandoned = False
         # Snapshot generation the batch actually evaluated against
         # (None without a generation source); stamped by the worker at
@@ -142,6 +151,8 @@ class _Pending:
         # deal for the phase record — the worker attributes
         # queue/batch/compile/device phases onto it.
         self.trace = tracing.current_trace()
+        # The submitter's innermost logged span: the queue wait's parent.
+        self.parent = tracing.current_span()
         self.phases = phases_mod.current_request()
 
 
@@ -154,7 +165,7 @@ class _BatchResult:
     __slots__ = (
         "live", "results", "error", "collected", "eval_ms", "assembly_s",
         "bucket", "flat_len", "pad_waste", "generation", "batch_phases",
-        "transfer_bytes", "gate_t",
+        "transfer_bytes", "gate_ns", "eval_ns",
     )
 
     def __init__(self):
@@ -199,6 +210,9 @@ class DynamicBatcher:
         self._max_queue = max_queue
         self._admission = admission
         self._name = name
+        # The serving role this batcher belongs to (the span attr).
+        self._party = name[: -len(".batcher")] if name.endswith(
+            ".batcher") else name
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         m, n = self.metrics, name
         self._c_submitted = m.counter(f"{n}.requests_submitted")
@@ -213,6 +227,7 @@ class DynamicBatcher:
             f"{n}.batch_keys", buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256)
         )
         self._h_latency = m.histogram(f"{n}.request_latency_ms")
+        self._h_evaluate = m.histogram(f"{n}.evaluate_ms")
         self._h_queue_wait = m.histogram(f"{n}.queue_wait_ms")
         self._h_pad_waste = m.histogram(
             f"{n}.pad_waste_ratio",
@@ -303,6 +318,12 @@ class DynamicBatcher:
         keys = list(keys)
         if not keys:
             raise ValueError("keys must not be empty")
+        # The submitter's wait, from submit to its slice of the results:
+        # the batcher's share of the calling request, in the span log.
+        with tracing.span("batcher.submit", record=False, party=self._party):
+            return self._submit(keys, deadline, tenant)
+
+    def _submit(self, keys, deadline, tenant):
         with self._cond:
             if self._closed:
                 raise RuntimeError("batcher is closed")
@@ -356,7 +377,8 @@ class DynamicBatcher:
                 self._c_deadline.inc()
                 raise DeadlineExceeded(
                     f"{self._name}: deadline passed after "
-                    f"{(time.monotonic() - pending.t0) * 1e3:.1f} ms in queue"
+                    f"{(time.perf_counter_ns() - pending.t0_ns) / 1e6:.1f}"
+                    " ms in queue"
                 )
         if pending.error is not None:
             raise pending.error
@@ -557,6 +579,7 @@ class DynamicBatcher:
             # Pre-dispatch deadline gate: requests that expired while
             # the batch was forming are dropped HERE, before any device
             # work, so an expired request never costs an evaluation.
+            gate_ns = time.perf_counter_ns()
             now = time.monotonic()
             live = []
             for p in batch:
@@ -612,7 +635,7 @@ class DynamicBatcher:
             record.flat_len = len(flat)
             record.pad_waste = pad_waste
             record.generation = generation
-            record.gate_t = now
+            record.gate_ns = gate_ns
             try:
                 # Chaos site: a worker-side fault here must fan out to
                 # every live request and leave the worker serving.
@@ -620,21 +643,23 @@ class DynamicBatcher:
                 telemetry = default_telemetry()
                 h2d_before = _h2d_bytes(telemetry)
                 sync_before = _sync_wait_ms(telemetry)
-                t_eval = time.perf_counter()
                 tracker = telemetry.compile_tracker
                 recorder = phases_mod.default_phase_recorder()
-                with self.metrics.timed(f"{self._name}.evaluate_ms"), \
-                        tracker.dispatch(
-                            f"{self._name}.evaluate",
-                            shape_key(("k", bucket)),
-                        ), \
-                        recorder.collect() as batch_phases:
+                with tracker.dispatch(
+                    f"{self._name}.evaluate", shape_key(("k", bucket))
+                ) as dispatch, tracing.span(
+                    "batcher.evaluate", record=False, bucket=bucket,
+                    keys=len(flat), party=self._party,
+                ) as timing, recorder.collect() as batch_phases:
+                    dispatch.timing = timing
                     # The batch-scoped record soaks up phase() brackets
                     # inside the evaluation path (h2d staging,
                     # compile-vs-compute in pir/server); the completion
                     # half re-attributes them to every live request.
                     results = list(self._evaluate(padded))
-                record.eval_ms = (time.perf_counter() - t_eval) * 1e3
+                record.eval_ms = timing.ms
+                record.eval_ns = (timing.start_ns, timing.end_ns)
+                self._h_evaluate.observe(record.eval_ms)
                 # Utilization split: the evaluation wall is busy time
                 # minus whatever it spent blocked in exposed transfer
                 # syncs — those are `staging_sync` bubbles, so the
@@ -736,21 +761,25 @@ class DynamicBatcher:
             self._c_slop.inc(slop_ms)
         # Batch-level stage aggregates (once per batch) ...
         tracing.add_span(
-            "batch_assembly", rec.assembly_s * 1e3,
+            "batch_assembly", rec.assembly_s * 1e3, end_ns=rec.gate_ns,
             bucket=rec.bucket, batch_keys=rec.flat_len,
         )
         tracing.add_span(
-            "device_compute", rec.eval_ms,
-            pad_waste_ratio=round(rec.pad_waste, 4),
+            "device_compute", rec.eval_ms, start_ns=rec.eval_ns[0],
+            end_ns=rec.eval_ns[1], pad_waste_ratio=round(rec.pad_waste, 4),
         )
         offset = 0
-        done = time.monotonic()
+        done_ns = time.perf_counter_ns()
         for p in rec.live:
             p.result = rec.results[offset:offset + len(p.keys)]
             offset += len(p.keys)
-            queue_wait_ms = (rec.gate_t - p.t0) * 1e3
+            queue_wait_ms = (rec.gate_ns - p.t0_ns) / 1e6
             self._h_queue_wait.observe(queue_wait_ms)
-            self._h_latency.observe((done - p.t0) * 1e3)
+            self._h_latency.observe((done_ns - p.t0_ns) / 1e6)
+            tracing.log_span(
+                "batcher.queue_wait", p.t0_ns, rec.gate_ns,
+                parent=p.parent, trace=p.trace, party=self._party,
+            )
             # ... and per-request spans grafted onto the submitting
             # thread's trace so /tracez decomposes each request.
             if p.trace is not None:

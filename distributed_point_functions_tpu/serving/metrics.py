@@ -7,16 +7,14 @@ client into the image. This registry is the stdlib answer: thread-safe
 instruments keyed by name, exported as one plain dict so any caller —
 the closed-loop bench, a debug endpoint, a log line — can serialize it.
 
-Timed regions double as profiler annotations: `registry.timed(name)`
-wraps the block in `utils.profiling.annotate(name)` (a named TraceAnnotation
-inside an active xprof trace) *and* records the wall-clock milliseconds
-into the `name` histogram, so the same instrumentation feeds both the
-metrics dict and a device trace.
+Latency histograms take a timing the caller already has (a span's,
+`observability/tracing.py`), so one timing feeds the histogram, the
+trace and the span log.
 
 **Labels.** The registry is a flat namespace; instruments that vary by
 role, level, or tier use the labeling convention `base{k=v,k2=v2}` —
 built with `labeled_name()` or by passing `labels=` to
-`counter`/`gauge`/`histogram`/`timed`. Keys are sorted, so the same
+`counter`/`gauge`/`histogram`. Keys are sorted, so the same
 label set always maps to the same instrument. The Prometheus renderer
 (`observability/exposition.py`) splits the suffix back into label
 pairs, turning e.g. `request_ms{role=leader}` and
@@ -37,13 +35,11 @@ from __future__ import annotations
 
 import bisect
 import collections
-import contextlib
 import threading
 import time
 from typing import Dict, Optional, Sequence
 
 from ..observability import tracing
-from ..utils.profiling import annotate
 
 # Default latency bucket upper bounds, in milliseconds.
 DEFAULT_BUCKETS_MS = (
@@ -255,18 +251,6 @@ class MetricsRegistry:
         with self._lock:
             return self._histograms.setdefault(name, Histogram(buckets))
 
-    @contextlib.contextmanager
-    def timed(self, name: str, labels: Optional[Dict] = None):
-        """Time the block into histogram `name` (ms) inside a profiler
-        annotation of the same name."""
-        hist = self.histogram(name, labels=labels)
-        t0 = time.perf_counter()
-        with annotate(name):
-            try:
-                yield
-            finally:
-                hist.observe((time.perf_counter() - t0) * 1e3)
-
     def export(self) -> dict:
         with self._lock:
             counters = dict(self._counters)
@@ -376,9 +360,6 @@ class ScopedRegistry:
         labels: Optional[Dict] = None,
     ) -> Histogram:
         return self._parent.histogram(name, buckets, self._merged(labels))
-
-    def timed(self, name: str, labels: Optional[Dict] = None):
-        return self._parent.timed(name, self._merged(labels))
 
     def scoped(self, labels: Dict[str, object]) -> "ScopedRegistry":
         return ScopedRegistry(self._parent, self._merged(labels))

@@ -551,11 +551,24 @@ class _Session:
             with tracing.trace_request(
                 f"{self._name}.request", role=self._name
             ):
+                # One timing for the request: its start and end feed
+                # both the waterfall's end-to-end total (when this call
+                # roots the record, not a wire entry point around it)
+                # and `<role>.request_ms`.
+                outer = phases_mod.current_request()
+                start_ns = time.perf_counter_ns()
                 with phases_mod.default_phase_recorder().request(
-                    self._name
-                ):
-                    with self.metrics.timed(f"{self._name}.request_ms"):
+                    self._name, start_ns=start_ns
+                ) as phases:
+                    try:
                         return self._server.handle_request(request)
+                    finally:
+                        end_ns = time.perf_counter_ns()
+                        if phases is not None and phases is not outer:
+                            phases.end_ns = end_ns
+                        self.metrics.histogram(
+                            f"{self._name}.request_ms"
+                        ).observe((end_ns - start_ns) / 1e6)
         finally:
             _TENANT.reset(tenant_token)
             _DEADLINE.reset(token)
@@ -609,7 +622,7 @@ class _Session:
             with phases_mod.default_phase_recorder().request(
                 self._name, fresh=trace_id is not None
             ):
-                with tracing.span("decode"), phases_mod.phase("respond"):
+                with phases_mod.phase("respond", span="decode"):
                     proto = pir_pb2.PirRequest.FromString(inner)
                     request = serialization.pir_request_from_proto(
                         self._server.dpf, proto
@@ -628,7 +641,7 @@ class _Session:
                         retry_after_s=getattr(e, "retry_after_s", 0.0),
                         trace_id=trace.trace_id,
                     )
-                with tracing.span("encode"), phases_mod.phase("respond"):
+                with phases_mod.phase("respond", span="encode"):
                     out = serialization.pir_response_to_proto(
                         response
                     ).SerializeToString()
@@ -959,13 +972,17 @@ class LeaderSession(_Session):
                 # retry ladder and the breaker exactly like a helper
                 # timeout would.
                 failpoints.fire("service.helper_leg", error=TransportTimeout)
-                t0 = time.perf_counter()
-                with self.metrics.timed("leader.helper_leg_ms"):
+                # One timing of the round trip: the rtt, the
+                # `leader.helper_leg_ms` histogram and the logged span
+                # (the parent of an in-process Helper's request).
+                with tracing.span("helper_leg", record=False) as leg:
                     data = self._transport.roundtrip(
                         payload, timeout=timeout,
                         on_sent=leader_share_once,
                     )
-                rtt_ms = (time.perf_counter() - t0) * 1e3
+                t0 = leg.start_ns / 1e9
+                rtt_ms = leg.ms
+                self.metrics.histogram("leader.helper_leg_ms").observe(rtt_ms)
                 if breaker is not None:
                     breaker.record_success()
                 self._retry_budget_earn()

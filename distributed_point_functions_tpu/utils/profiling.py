@@ -13,6 +13,10 @@ internals:
     with annotate("expand"):          # named region inside a trace
         selections = evaluate_selection_blocks(...)
 
+    @jax.jit
+    @scoped("pir.expand")             # program scope of the traced ops
+    def expand(...): ...
+
 Both are no-ops (with a one-time log) if the profiler is unavailable,
 so library code can call them unconditionally.
 """
@@ -20,6 +24,7 @@ so library code can call them unconditionally.
 from __future__ import annotations
 
 import contextlib
+import functools
 import logging
 
 logger = logging.getLogger(__name__)
@@ -69,3 +74,38 @@ def annotate(name: str):
     if prof is None:
         return contextlib.nullcontext()
     return prof.TraceAnnotation(name)
+
+
+_is_capturing = _UNRESOLVED
+
+
+def profiler_active() -> bool:
+    """Whether a profiler session is capturing host annotations right
+    now (one native call; resolved once, like `annotate`)."""
+    global _is_capturing
+    if _is_capturing is _UNRESOLVED:
+        prof = _profiler()
+        _is_capturing = getattr(
+            getattr(prof, "TraceAnnotation", None), "is_enabled", None
+        ) or (lambda: False)
+    return _is_capturing()
+
+
+def scoped(name: str):
+    """Decorator for a function that a `jax.jit` traces: its ops run
+    under `jax.named_scope(name)`, so every op of the compiled program
+    carries `name` in its HLO metadata (`op_name`), and a device profile
+    shows it as the op's program scope. Metadata only: the compiled
+    program is the same."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def run(*args, **kwargs):
+            import jax
+
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+
+        return run
+
+    return wrap
