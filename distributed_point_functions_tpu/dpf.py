@@ -41,7 +41,7 @@ from .observability import tracing
 from .observability.device import default_telemetry
 from .ops import aes, limb
 from .utils.profiling import scoped
-from .value_types import ValueType
+from .value_types import IntType, ValueType
 
 U32 = jnp.uint32
 _U128_MASK = (1 << 128) - 1
@@ -436,6 +436,72 @@ def _expand_levels_limb_fn(num_levels: int, hash_leaves: bool = False):
     return run
 
 
+def _limb_levels(p: int, num_levels: int) -> int:
+    """How many of `num_levels` levels the plane program runs in limb
+    space from `p` seeds. Plane layout carries its padding through every
+    level (dead lanes double along with live ones), so entering at the
+    root from p=1 seed would do 32x the AES work forever: the first
+    levels stay in limb space until the width fills the 32-block words
+    (exactly, or within ~12% for non-power-of-two prefix counts)."""
+    limb_levels = 0
+    while limb_levels < num_levels:
+        width = p << limb_levels
+        if width % 32 == 0 or width >= 256:
+            break
+        limb_levels += 1
+    return limb_levels
+
+
+def _bitrev_order(bits: int) -> np.ndarray:
+    """order[position] = path for `bits` doubling levels that append
+    [all-left; all-right]: the bit reversal of the position."""
+    pos = np.arange(1 << bits)
+    path = np.zeros_like(pos)
+    for i in range(bits):
+        path |= ((pos >> i) & 1) << (bits - 1 - i)
+    return path
+
+
+def _gather_exit(state, ctrl, order, n0):
+    """Leaves in the limb program's layout: uint32[n0 * 2^PL, 4] seeds
+    and uint32[n0 * 2^PL] control bits in natural (prefix-major) order,
+    by a trace-time-static gather of single leaves."""
+    from .ops.aes_bitslice import planes_to_limbs
+
+    n32 = 32 * ctrl.shape[0] // order.size
+    out = planes_to_limbs(state)  # [2^PL * n32, 4], lane-ordered
+    shifts = jnp.arange(32, dtype=U32)
+    ctrl_bits = ((ctrl[:, None] >> shifts) & U32(1)).reshape(-1)
+    pos = np.argsort(order)
+    path = np.arange(order.size)
+    lane = pos[path][:, None] * n32 + np.arange(n0)[None, :]
+    perm = jnp.asarray(
+        np.ascontiguousarray(lane.T.reshape(-1))  # prefix-major
+    )
+    return out[perm], ctrl_bits[perm]
+
+
+def _direct_exit(state, ctrl, order, n0):
+    """Leaves as the lane-dense leaf stage (`_leaf_stage_lanes`) takes
+    them, with no reorder: uint32[4, A, n0, B] limbs, limbs leading and
+    the path positions on the last axis (position = a * B + b, so one
+    position's n0 <= 32 prefixes are one word of every plane), and the
+    packed control words uint32[A * B]. The positions stay in emission
+    order, the bit reversal of the path; the leaf stage undoes it on
+    whole rows while it lays out the output."""
+    from .ops.aes_bitslice import planes_to_limb_rows
+
+    if ctrl.shape[0] != order.size or not np.array_equal(
+        order, _bitrev_order(order.size.bit_length() - 1)
+    ):
+        raise ValueError(
+            "the direct exit needs one 32-prefix word per path position "
+            "and the doubling levels' order"
+        )
+    lanes = min(128, order.size)
+    return planes_to_limb_rows(state, lanes)[:, :, :n0], ctrl
+
+
 @functools.lru_cache(maxsize=None)
 def _expand_levels_planes_fn(num_levels: int, level_kernel: bool = False,
                              hash_leaves: bool = False,
@@ -445,15 +511,19 @@ def _expand_levels_planes_fn(num_levels: int, level_kernel: bool = False,
                              head_cap: int = 0,
                              tail_kind: str = "concat",
                              head_kind: str = "concat",
-                             walk_compact: bool = False):
+                             walk_compact: bool = False,
+                             exit: str = "gather"):
     """`_expand_levels_limb_fn` computed in bitsliced plane layout (see
     `pir/dense_eval_planes.py` for the design): children are appended
     [all-left; all-right] per level so the lane order ends up
-    path-bit-reversed and prefix-minor; a trace-time-static gather
-    restores the natural interleaved order, making the output
-    bit-identical to the limb program. Shared correction words only (one
-    key), like the limb program. With `level_kernel` each level runs the
-    fused Pallas VMEM kernel (`ops/expand_planes_pallas.py`); with
+    path-bit-reversed and prefix-minor. With `exit="gather"` a
+    trace-time-static gather restores the natural interleaved order,
+    making the output bit-identical to the limb program; with
+    `exit="direct"` the leaves leave as `_direct_exit` describes, for
+    the lane-dense leaf stage (pure doubling, n0 <= 32 prefixes and
+    `hash_leaves` only). Shared correction words only (one key), like
+    the limb program. With `level_kernel` each level runs the fused
+    Pallas VMEM kernel (`ops/expand_planes_pallas.py`); with
     `hash_leaves` the leaf value MMO hash runs before leaving plane
     layout (no extra transpose round-trip for single-block value
     types)."""
@@ -466,7 +536,6 @@ def _expand_levels_planes_fn(num_levels: int, level_kernel: bool = False,
             limbs_to_planes,
             mmo_hash_planes,
             pack_select_bits,
-            planes_to_limbs,
         )
         from .ops.expand_planes_pallas import (
             expand_level_planes_pallas,
@@ -474,18 +543,7 @@ def _expand_levels_planes_fn(num_levels: int, level_kernel: bool = False,
         )
         from .pir.dense_eval_planes import expand_level_planes
 
-        # Plane layout carries its padding through every level (dead
-        # lanes double along with live ones), so entering at the root
-        # from p=1 seed would do 32x the AES work forever. Run the first
-        # levels in limb space until the width fills the 32-block words
-        # (exactly, or within ~12% for non-power-of-two prefix counts).
-        p = seeds.shape[0]
-        limb_levels = 0
-        while limb_levels < num_levels:
-            width = p << limb_levels
-            if width % 32 == 0 or width >= 256:
-                break
-            limb_levels += 1
+        limb_levels = _limb_levels(seeds.shape[0], num_levels)
         for i in range(limb_levels):
             seeds, control = _expand_level_body(
                 seeds, control, cw_seeds[i], cw_left[i], cw_right[i]
@@ -497,7 +555,6 @@ def _expand_levels_planes_fn(num_levels: int, level_kernel: bool = False,
             seeds = jnp.pad(seeds, ((0, pad), (0, 0)))
             control = jnp.pad(control, ((0, pad),))
         n32 = n0 + pad
-        shifts = jnp.arange(32, dtype=U32)
 
         state = limbs_to_planes(seeds)
         ctrl = pack_select_bits(control.astype(U32))
@@ -641,8 +698,6 @@ def _expand_levels_planes_fn(num_levels: int, level_kernel: bool = False,
                 state = value_hash_planes_pallas(state, ctrl, zeros_vc)
             else:
                 state = mmo_hash_planes(fixed_keys.RK_VALUE, state)
-        out = planes_to_limbs(state)  # [2^PL * n32, 4], lane-ordered
-        ctrl_bits = ((ctrl[:, None] >> shifts) & U32(1)).reshape(-1)
         # lane(path, prefix) = position(path) * n32 + prefix over the
         # plane levels only (the limb prefix is already natural/
         # interleaved); natural index = prefix * 2^PL + path. Static per
@@ -656,7 +711,7 @@ def _expand_levels_planes_fn(num_levels: int, level_kernel: bool = False,
 
         # Compose each phase's node order (walk phases emit natural
         # offsets, or offset-major tiles in compact mode; doubling
-        # phases append [all-left; all-right]); the exit gather is
+        # phases append [all-left; all-right]); the gather exit is
         # argsort of the composition. Pure doubling degenerates to the
         # classic bit-reversal. walk_plan mirrors the kernel call
         # sites exactly, so the order can never disagree with the
@@ -683,34 +738,28 @@ def _expand_levels_planes_fn(num_levels: int, level_kernel: bool = False,
                 order = walk_order(order, tail_r)
             else:
                 order = tail_node_permutation(order, tail_r, tile_nodes)[0]
-        pos = np.argsort(order)
-        path = np.arange(1 << plane_levels)
-        lane = pos[path][:, None] * n32 + np.arange(n0)[None, :]
-        perm = jnp.asarray(
-            np.ascontiguousarray(lane.T.reshape(-1))  # prefix-major
-        )
-        return out[perm], ctrl_bits[perm]
+        if exit == "direct":
+            return _direct_exit(state, ctrl, order, n0)
+        return _gather_exit(state, ctrl, order, n0)
 
     return run
 
 
-def _expand_levels_fn(num_levels: int, hash_leaves: bool = False):
-    """Dispatch the fused expansion program: `DPF_TPU_EXPAND_LEVELS` =
+def _planes_plan(hash_leaves: bool):
+    """The plane program's knobs (`_expand_levels_planes_fn` keyword
+    arguments), or None for the limb program: `DPF_TPU_EXPAND_LEVELS` =
     `limb` | `planes` | `auto` (default: planes on TPU, limb elsewhere).
     On TPU the plane levels run the fused Pallas kernels
-    (`DPF_TPU_LEVEL_KERNEL`); a kernel failure raises. `hash_leaves`
-    fuses the leaf value MMO hash into the same program (single-block
-    value types)."""
+    (`DPF_TPU_LEVEL_KERNEL`); a kernel failure raises."""
     from .utils.runtime import planes_selected
 
     if not planes_selected("DPF_TPU_EXPAND_LEVELS"):
-        return _expand_levels_limb_fn(num_levels, hash_leaves=hash_leaves)
+        return None
     from .pir import dense_eval_planes as _dep
 
     mode = _dep._level_kernel_enabled()
     if not mode:
-        return _expand_levels_planes_fn(num_levels,
-                                        hash_leaves=hash_leaves)
+        return {}
     kinds = {}
     if mode == "walk":
         kinds = {
@@ -738,13 +787,37 @@ def _expand_levels_fn(num_levels: int, hash_leaves: bool = False):
             head_req = 0
     else:
         head_req = -1 if mode == "walk" else 0
-    return _expand_levels_planes_fn(num_levels, level_kernel=True,
-                                    hash_leaves=hash_leaves,
-                                    tail_req=tail_req,
-                                    tail_tile_target=tail_tile,
-                                    head_req=head_req,
-                                    head_cap=head_cap,
-                                    **kinds)
+    return dict(level_kernel=True, tail_req=tail_req,
+                tail_tile_target=tail_tile, head_req=head_req,
+                head_cap=head_cap, **kinds)
+
+
+def _leaf_exit(p: int, num_levels: int) -> str:
+    """The exit a hash-fused expansion of `num_levels` levels from `p`
+    seeds takes: "direct" where the plane program only doubles (no
+    fused head, tail or walk phase) and one 32-prefix word holds every
+    prefix, else "gather"."""
+    plan = _planes_plan(hash_leaves=True)
+    if (plan is None or num_levels == 0 or plan.get("tail_req")
+            or plan.get("head_req") or plan.get("tail_kind")
+            or (p << _limb_levels(p, num_levels)) > 32):
+        return "gather"
+    return "direct"
+
+
+def _expand_levels_fn(num_levels: int, hash_leaves: bool = False,
+                      exit: str = "gather"):
+    """Dispatch the fused expansion program (`_planes_plan`): the limb
+    program or the plane program with the given `exit`. `hash_leaves`
+    fuses the leaf value MMO hash into the same program (single-block
+    value types)."""
+    plan = _planes_plan(hash_leaves)
+    if plan is None:
+        if exit != "gather":
+            raise ValueError("the limb program has only the gather exit")
+        return _expand_levels_limb_fn(num_levels, hash_leaves=hash_leaves)
+    return _expand_levels_planes_fn(num_levels, hash_leaves=hash_leaves,
+                                    exit=exit, **plan)
 
 
 @jax.jit
@@ -943,6 +1016,69 @@ def _leaf_stage(seeds, control, vc_dev, vtype, cepb, num_blocks, party,
     if party == 1:
         corrected = vtype.dev_neg(corrected)
     return corrected
+
+
+def _add_limb_rows(a, b):
+    """Carry-propagating add of two little-endian limb lists (each limb
+    an array, so the leaves stay on the lanes)."""
+    out = []
+    carry = None
+    for x, y in zip(a, b):
+        s = x + y
+        c = (s < x).astype(U32)
+        if carry is not None:
+            s2 = s + carry
+            c = c | (s2 < s).astype(U32)
+            s = s2
+        out.append(s)
+        carry = c
+    return out
+
+
+def _neg_limb_rows(a):
+    """Two's complement of a little-endian limb list."""
+    out = []
+    carry = U32(1)
+    for x in a:
+        s = ~x + carry
+        carry = (s < carry).astype(U32)
+        out.append(s)
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=("bits", "cepb", "party"))
+@scoped("dpf.leaf_values")
+def _leaf_stage_lanes(limbs, ctrl, vc, bits, cepb, party):
+    """`_leaf_stage` and the flatten for `IntType(bits)` values, on the
+    direct exit's lane-dense limbs (`_direct_exit`): parse the first
+    `cepb` elements of each hashed block, add the value correction
+    uint32[cepb, nlimbs] where the control bit is set, negate for party
+    1, and return uint32[n0 * 2^PL * cepb, nlimbs] in domain order, the
+    leaves' bit-reversed positions restored by two gathers of whole rows
+    around one transpose that also interleaves the elements."""
+    _, a_rows, n0, b_lanes = limbs.shape
+    nlimbs = max(1, bits // 32)
+    low = U32((1 << bits) - 1) if bits < 32 else None
+    shifts = jnp.arange(n0, dtype=U32)[None, :, None]
+    mask = ((ctrl.reshape(a_rows, 1, b_lanes) >> shifts) & U32(1)) != 0
+    elems = []
+    for e in range(cepb):
+        if low is None:
+            v = [limbs[e * nlimbs + h] for h in range(nlimbs)]
+        else:
+            v = [(limbs[e * bits // 32] >> U32(e * bits % 32)) & low]
+        c = _add_limb_rows(v, [vc[e, h] for h in range(nlimbs)])
+        v = [jnp.where(mask, x, y) for x, y in zip(c, v)]
+        if party == 1:
+            v = _neg_limb_rows(v)
+        if low is not None:
+            v = [v[0] & low]
+        elems.append(jnp.stack(v))  # [nlimbs, A, n0, B]
+    # path = bitrev(b) * A + bitrev(a) for position a * B + b.
+    z = jnp.stack(elems, axis=2)[:, _bitrev_order(a_rows.bit_length() - 1)]
+    z = z.reshape(nlimbs, a_rows * cepb, n0, b_lanes).transpose(0, 2, 3, 1)
+    z = z[:, :, _bitrev_order(b_lanes.bit_length() - 1)]
+    return z.reshape(nlimbs, -1).T
 
 
 @functools.partial(
@@ -1486,11 +1622,6 @@ class DistributedPointFunction:
         `_device_correction_words(key, start, stop)` when the caller has
         already placed them.
         """
-        if stop - start > 62:
-            raise ValueError(
-                "trying to expand more than 62 tree levels at once; insert "
-                "intermediate hierarchy levels"
-            )
         if stop == start:
             if hash_leaves:
                 return (
@@ -1576,6 +1707,56 @@ class DistributedPointFunction:
             lambda x: x.reshape((-1,) + x.shape[2:]), values
         )
 
+    def _expand_leaf_values(self, seeds, control, key: DpfKey, start: int,
+                            stop: int, hierarchy_level: int,
+                            staged_cw=None):
+        """Expand seeds from tree level `start` to `stop` and return the
+        leaf values flattened to domain order. Integer values of one
+        block take the direct exit and the lane-dense leaf stage where
+        the expansion plan allows it (`_leaf_exit`); every other value
+        type, and the limb program, take the gather exit and
+        `_leaf_values`. The `dpf.expand` span names the exit taken."""
+        if stop - start > 62:
+            raise ValueError(
+                "trying to expand more than 62 tree levels at once; insert "
+                "intermediate hierarchy levels"
+            )
+        vt = self.parameters[hierarchy_level].value_type
+        fuse = self._blocks_needed[hierarchy_level] == 1
+        exit = "gather"
+        if fuse and isinstance(vt, IntType):
+            exit = _leaf_exit(seeds.shape[0], stop - start)
+        with tracing.span("dpf.expand", record=False, exit=exit):
+            if exit == "gather":
+                seeds, control = self._expand(
+                    seeds, control, key, start, stop, hash_leaves=fuse,
+                    staged_cw=staged_cw,
+                )
+            else:
+                if staged_cw is None:
+                    staged_cw = self._device_correction_words(
+                        key, start, stop
+                    )
+                limbs, ctrl = _expand_levels_fn(
+                    stop - start, hash_leaves=True, exit=exit
+                )(seeds, control, *staged_cw)
+        with tracing.span("dpf.leaf_values", record=False):
+            if exit == "gather":
+                return self._leaf_values(
+                    seeds, control, key, hierarchy_level, pre_hashed=fuse
+                )
+            cepb = 1 << (
+                self.parameters[hierarchy_level].log_domain_size
+                - self._hierarchy_to_tree[hierarchy_level]
+            )
+            vc = np.stack([
+                vt.host_const(v)
+                for v in self._value_correction_host(key, hierarchy_level)
+            ][:cepb])
+            return _leaf_stage_lanes(
+                limbs, ctrl, vc, bits=vt.bits, cepb=cepb, party=key.party
+            )
+
     def evaluate_next(self, prefixes: Sequence[int], ctx: EvaluationContext):
         """Evaluate the hierarchy level after `ctx.previous_hierarchy_level`.
 
@@ -1637,16 +1818,10 @@ class DistributedPointFunction:
                     self._device_correction_words(key, 0, stop_level)
                     if stop_level > 0 else None
                 )
-            fuse = self._blocks_needed[hierarchy_level] == 1
-            with tracing.span("dpf.expand", record=False):
-                seeds, control = self._expand(
-                    seeds, control, key, 0, stop_level, hash_leaves=fuse,
-                    staged_cw=staged_cw,
-                )
-            with tracing.span("dpf.leaf_values", record=False):
-                out = self._leaf_values(
-                    seeds, control, key, hierarchy_level, pre_hashed=fuse
-                )
+            out = self._expand_leaf_values(
+                seeds, control, key, 0, stop_level, hierarchy_level,
+                staged_cw=staged_cw,
+            )
             ctx.previous_hierarchy_level = hierarchy_level
             return out
 
@@ -1668,12 +1843,8 @@ class DistributedPointFunction:
             tree_indices, prev_hl, update_ctx, ctx
         )
         start_level = self._hierarchy_to_tree[prev_hl]
-        fuse = self._blocks_needed[hierarchy_level] == 1
-        seeds, control = self._expand(
-            seeds, control, key, start_level, stop_level, hash_leaves=fuse
-        )
-        values = self._leaf_values(
-            seeds, control, key, hierarchy_level, pre_hashed=fuse
+        values = self._expand_leaf_values(
+            seeds, control, key, start_level, stop_level, hierarchy_level
         )
 
         # Select the per-prefix output spans.

@@ -274,3 +274,32 @@ def aes_encrypt_select_bs(
     if pad:
         out = out[:n]
     return out.reshape(shape)
+
+
+def _transpose32_rows(x: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """`_transpose32` over `axis` (size 32) in place of the last axis:
+    row `b` of the result holds bit `b` of every row of `x`, so the
+    butterflies combine whole rows and the minor axes stay lane-dense."""
+    x = jnp.moveaxis(x, axis, 0)
+    shape = x.shape
+    masks = (0x0000FFFF, 0x00FF00FF, 0x0F0F0F0F, 0x33333333, 0x55555555)
+    j = 16
+    for m in masks:
+        r = x.reshape((32 // (2 * j), 2, j) + shape[1:])
+        a = r[:, 0]
+        b = r[:, 1]
+        t = (a ^ (b << j)) & U32(~m & 0xFFFFFFFF)
+        x = jnp.stack([a ^ t, b ^ (t >> j)], axis=1).reshape(shape)
+        j >>= 1
+    return jnp.moveaxis(x, 0, axis)
+
+
+def planes_to_limb_rows(planes: jnp.ndarray, lanes: int) -> jnp.ndarray:
+    """uint32[16, 8, G] bit planes -> uint32[4, G // lanes, 32, lanes]
+    limbs with the blocks on lanes: out[l, r, i, c] is limb l of block
+    32 * (r * lanes + c) + i. The same bits as `planes_to_limbs`, laid
+    out limb-major for a consumer that keeps the blocks lane-dense;
+    the plane tiles are only regrouped, never split."""
+    g = planes.shape[-1]
+    x = planes.reshape(4, 32, g // lanes, lanes).transpose(0, 2, 1, 3)
+    return _transpose32_rows(x, 2)

@@ -3,19 +3,25 @@
 Forces JAX onto a virtual 8-device CPU mesh so sharding tests run hermetically
 without TPU hardware (the driver's dryrun does the same). ``XLA_FLAGS`` must be
 set before the CPU client is created (env is read at backend-init time, which
-pytest's conftest-first import order guarantees). The persistent compile cache
-is off: sessions point it at ``<repo>/.jax_cache``, and CPU test programs have
-no business there.
+pytest's conftest-first import order guarantees); LLVM optimisation is off for
+the CPU programs, and modules that take minutes start first (below). The
+persistent compile cache is off: sessions point it at ``<repo>/.jax_cache``,
+and CPU test programs have no business there.
 """
 
+import collections
 import os
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + " --xla_force_host_platform_device_count=8"
-    ).strip()
+    _flags += " --xla_force_host_platform_device_count=8"
+# Most of the suite's time is compiling programs (interpret-mode Pallas
+# kernels, unrolled AES circuits) that then run once on tiny shapes; LLVM's
+# optimisation passes cost more there than they save.
+if "xla_backend_optimization_level" not in _flags:
+    _flags += " --xla_backend_optimization_level=0"
+os.environ["XLA_FLAGS"] = _flags.strip()
 
 import jax  # noqa: E402
 
@@ -49,3 +55,46 @@ def _clear_jax_caches_between_modules():
     """
     yield
     jax.clear_caches()
+
+
+# Modules that take minutes each on the CPU (interpret-mode Pallas kernels,
+# 8-device shard_map programs), longest first. Under `--dist loadfile` a
+# module runs whole on one worker, and xdist hands modules out in the
+# order given here; its own order (most tests first) starts these last, so
+# one worker ran on alone for minutes after the others had finished. The
+# other modules follow, most tests first.
+_LONGEST_MODULES = (
+    "test_expand_pallas_fused.py",
+    "test_expand_pallas.py",
+    "test_eval_paths_planes.py",
+    "test_mesh_serving.py",
+    "test_streaming_pir.py",
+    "test_sharded.py",
+    "test_dense_eval_planes_v2.py",
+    "test_dpf.py",
+    "test_pallas_fast.py",
+    "test_dense_eval_planes.py",
+    "test_pir.py",
+    "test_dcf.py",
+    "test_incremental_reuse.py",
+    "test_full_domain_direct_exit.py",
+    "test_core_fast.py",
+    "test_chaos.py",
+)
+
+
+def pytest_configure(config):
+    if hasattr(config.option, "loadscopereorder"):  # pytest-xdist loaded
+        config.option.loadscopereorder = False
+
+
+def pytest_collection_modifyitems(config, items):
+    counts = collections.Counter(item.path.name for item in items)
+
+    def rank(item):
+        name = item.path.name
+        if name in _LONGEST_MODULES:
+            return (0, _LONGEST_MODULES.index(name))
+        return (1, -counts[name])
+
+    items.sort(key=rank)  # stable: each module's tests keep their order

@@ -151,3 +151,33 @@ def test_streaming_pallas2_accumulate_in_shard_map_compiles(topo):
         sharding=NamedSharding(mesh, P("shard", "key")),
     )
     _compile(mapped, db, sel)
+
+
+@pytest.mark.parametrize("party", [0, 1])
+def test_full_domain_direct_exit_compiles(one_chip, party):
+    """The full-domain cell's exit and leaf stage at log domain 20,
+    uint64 (2^19 leaves of 32-block words at 2^14 positions): no gather
+    moves fewer than one 32-leaf row, and the output leaves the program
+    in the layout `{0,1}` the API returns it in (no relayout after)."""
+    from distributed_point_functions_tpu import dpf as dpf_mod
+
+    positions = 1 << 14
+    order = dpf_mod._bitrev_order(14)
+
+    def full_domain(state, ctrl, vc):
+        limbs, c = dpf_mod._direct_exit(state, ctrl, order, 32)
+        return dpf_mod._leaf_stage_lanes(limbs, c, vc, bits=64, cepb=2,
+                                         party=party)
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, U32, sharding=one_chip)
+
+    text = jax.jit(full_domain).lower(
+        shape(16, 8, positions), shape(positions), shape(2, 2)
+    ).compile().as_text()
+    layout = text.split("entry_computation_layout={")[1].split("\n")[0]
+    assert "->u32[1048576,2]{0,1:T(2,128)}" in layout
+    for line in text.splitlines():
+        if " gather(" in line:
+            slices = line.split("slice_sizes={")[1].split("}")[0]
+            assert np.prod([int(s) for s in slices.split(",")]) >= 32 * 4
