@@ -25,10 +25,13 @@ are embedded in seed LSBs exactly like the reference
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import os
 import secrets
+import threading
+import weakref
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -94,8 +97,8 @@ class PartialEvaluations:
     a Python dict of 128-bit ints costs ~1 µs/entry per level, which adds
     seconds per level at heavy-hitters scale (2^20 live prefixes). This
     store keeps sorted numpy arrays instead: writes are vectorized and
-    lookups are one `searchsorted` per batch. Prefixes wider than 63 bits
-    fall back to an object-dtype array (still sorted/searchable).
+    lookups (`_lookup`) are one `searchsorted` per batch. Prefixes wider
+    than 63 bits fall back to an object-dtype array (still sorted).
     """
 
     prefixes: np.ndarray  # sorted; uint64 or object (for >63-bit levels)
@@ -129,36 +132,6 @@ class PartialEvaluations:
             control[i] = t
         return cls.build([p for p, _ in items], seeds, control)
 
-    def lookup(self, wanted_list):
-        """Seeds/control rows for `wanted_list` prefixes (vectorized);
-        raises ValueError naming the first missing prefix."""
-        wide = self.prefixes.dtype == object
-        try:
-            wanted = np.array(
-                wanted_list, dtype=object if wide else np.uint64
-            )
-        except OverflowError:
-            # A >64-bit prefix cannot be in a uint64-keyed store.
-            missing = next(
-                p for p in wanted_list if p > 0xFFFFFFFFFFFFFFFF
-            )
-            raise ValueError(
-                f"prefix {int(missing)} not present in "
-                f"ctx.partial_evaluations"
-            ) from None
-        pos = np.searchsorted(self.prefixes, wanted)
-        pos_clipped = np.minimum(pos, len(self.prefixes) - 1)
-        ok = (pos < len(self.prefixes)) & (
-            self.prefixes[pos_clipped] == wanted
-        )
-        if not np.all(ok):
-            missing = wanted[np.argmin(ok)]
-            raise ValueError(
-                f"prefix {int(missing)} not present in "
-                f"ctx.partial_evaluations"
-            )
-        return self.seeds[pos_clipped], self.control[pos_clipped]
-
     def items(self):
         """(prefix, (seed uint128, control)) pairs in sorted order."""
         for i in range(len(self.prefixes)):
@@ -166,6 +139,154 @@ class PartialEvaluations:
                 aes.limbs_to_u128(self.seeds[i]),
                 int(self.control[i]),
             )
+
+
+def _lookup(keys: np.ndarray, wanted: np.ndarray):
+    """(positions int64, found bool or None when all are) of `wanted`
+    in the sorted unique `keys`; O(n) when `wanted` is `keys` with
+    repeats (sorted, every key present: each frontier node has a child
+    requested), else a binary search."""
+    if keys.dtype != wanted.dtype:
+        keys = keys.astype(object)
+        wanted = wanted.astype(object)
+    if len(wanted) and len(keys):
+        new = _run_starts(wanted)
+        if int(new.sum()) == len(keys) and np.array_equal(wanted[new], keys):
+            return np.cumsum(new, dtype=np.int64) - 1, None
+    pos = np.searchsorted(keys, wanted)
+    clipped = np.minimum(pos, max(len(keys) - 1, 0))
+    found = (pos < len(keys)) & (keys[clipped] == wanted)
+    return clipped.astype(np.int64), (None if found.all() else found)
+
+
+def _run_starts(a: np.ndarray) -> np.ndarray:
+    """bool mask of the first element of each run of equal values (of a
+    sorted array: its unique values)."""
+    new = np.ones(len(a), dtype=bool)
+    if len(a) > 1:
+        new[1:] = a[1:] != a[:-1]
+    return new
+
+
+class DevicePartialEvaluations:
+    """`PartialEvaluations` whose seeds stay on the device, as packed
+    rows uint32[n_pad, 4] (the control bit in the seed's cleared LSB,
+    the wire convention) in `prefixes` order. The host arrays
+    (`seeds`, `control`, `items`: what serialization reads) are copied
+    back on first read only, counted in `dpf.hier.readback_bytes`."""
+
+    def __init__(self, prefixes: np.ndarray, rows):
+        self.prefixes = prefixes  # sorted tree indices, uint64 or object
+        self.rows = rows
+        self._host: Optional[PartialEvaluations] = None
+
+    def __len__(self) -> int:
+        return len(self.prefixes)
+
+    def _materialize(self) -> PartialEvaluations:
+        if self._host is None:
+            rows = np.asarray(self.rows)[: len(self.prefixes)]
+            tracing.runtime_counters.inc("dpf.hier.readback_bytes",
+                                         rows.nbytes)
+            self._host = PartialEvaluations(
+                prefixes=self.prefixes,
+                seeds=rows & _CLEAR_LSB,
+                control=rows[:, 0] & np.uint32(1),
+            )
+        return self._host
+
+    @property
+    def seeds(self) -> np.ndarray:
+        return self._materialize().seeds
+
+    @property
+    def control(self) -> np.ndarray:
+        return self._materialize().control
+
+    def items(self):
+        return self._materialize().items()
+
+
+@dataclasses.dataclass
+class _Leaves:
+    """Pre-hash leaves a context keeps on the device so that the next
+    level resumes from them by position: the whole expansion of the
+    last level's `nodes` (`levels` > 0: after a full-domain or few-node
+    level), or the leaves its outputs were read from (`levels` 0, after
+    the rows exit; `nodes` are then those leaves' tree indices).
+
+    `rows` are packed like `DevicePartialEvaluations.rows`: uint32[N, 4]
+    in natural order (`layout="natural"`: leaf (q, c) of node q, path c
+    at q * 2^levels + c, or at `row_of[q * 2^levels + c]` when given),
+    or the plane program's lanes as limb rows (`layout="planes"`):
+    uint32[R, 4, 32, T], limb l of block 32 * (r * T + t) + i at
+    [r, l, i, t]. There leaf (q, c) sits at lane
+    bitrev(c mod 2^P) * n32 + q * 2^L + c / 2^P, for L = `limb_levels`
+    levels run in limb space and P = levels - L plane levels. `nodes`
+    are sorted and unique, `level` is the hierarchy level of the
+    leaves, and `pe` the partial evaluations they extend: valid while
+    the context holds both."""
+
+    rows: object
+    levels: int
+    layout: str = "natural"
+    limb_levels: int = 0
+    n32: int = 0
+    nodes: Optional[np.ndarray] = None
+    row_of: Optional[np.ndarray] = None
+    level: int = -1
+    pe: object = None
+
+    def position(self, q: np.ndarray, c: np.ndarray) -> np.ndarray:
+        if self.layout == "natural":
+            pos = (q << self.levels) + c
+            return pos if self.row_of is None else self.row_of[pos]
+        plane = self.levels - self.limb_levels
+        pos = _bitrev_order(plane)[c & ((1 << plane) - 1)]
+        return pos * self.n32 + (q << self.limb_levels) + (c >> plane)
+
+
+# Device bytes that the contexts' frontiers (device partial evaluations
+# and kept leaves) may hold together. Past it, the contexts evaluated
+# least recently move their partial evaluations to the host and drop
+# their leaves: they resume by walking from there, as a deserialized
+# context does.
+FRONTIER_DEVICE_BYTES = 2 << 30
+_frontiers_lock = threading.Lock()
+_frontiers: "collections.OrderedDict[int, weakref.ref]" = (
+    collections.OrderedDict()
+)
+
+
+def _frontier_bytes(ctx: "EvaluationContext") -> int:
+    held = 0 if ctx._leaves is None else ctx._leaves.rows.nbytes
+    if isinstance(ctx.partial_evaluations, DevicePartialEvaluations):
+        held += ctx.partial_evaluations.rows.nbytes
+    return held
+
+
+def _hold_frontier(ctx: "EvaluationContext") -> None:
+    """Record `ctx` as the latest holder of device frontier state, and
+    move the state of the least recent holders to the host while all of
+    it exceeds `FRONTIER_DEVICE_BYTES` (never `ctx`'s own)."""
+    with _frontiers_lock:
+        _frontiers.pop(id(ctx), None)
+        if _frontier_bytes(ctx):
+            _frontiers[id(ctx)] = weakref.ref(ctx)
+        total = 0
+        for k in reversed(list(_frontiers)):
+            held = _frontiers[k]()
+            if held is None:
+                del _frontiers[k]
+                continue
+            total += _frontier_bytes(held)
+            if total > FRONTIER_DEVICE_BYTES and held is not ctx:
+                total -= _frontier_bytes(held)
+                pe = held.partial_evaluations
+                if isinstance(pe, DevicePartialEvaluations):
+                    held.partial_evaluations = pe._materialize()
+                held._leaves = None
+                del _frontiers[k]
 
 
 @dataclasses.dataclass
@@ -176,9 +297,15 @@ class EvaluationContext:
     previous_hierarchy_level: int = -1
     # (prefix -> seed uint128, control bit) at tree level
     # hierarchy_to_tree[partial_evaluations_level]: a PartialEvaluations
-    # store, a plain dict (accepted for compatibility), or empty.
+    # store (host or device), a plain dict (accepted for compatibility),
+    # or empty.
     partial_evaluations: object = dataclasses.field(default_factory=dict)
     partial_evaluations_level: int = 0
+    # Not part of the checkpoint: the last expansion's leaves on the
+    # device (a deserialized context resumes from partial_evaluations).
+    _leaves: Optional[_Leaves] = dataclasses.field(
+        default=None, repr=False, compare=False
+    )
 
 
 @dataclasses.dataclass
@@ -413,14 +540,23 @@ def _expand_level_body(seeds, control, cw_seed, cw_left, cw_right):
 _expand_level = jax.jit(_expand_level_body)
 
 
+def _pack_rows(seeds, control):
+    """Seeds uint32[n, 4] (LSB clear) and control bits uint32[n] as
+    packed rows: the control bit in the seed's LSB."""
+    return seeds.at[:, 0].set(seeds[:, 0] | control)
+
+
 @functools.lru_cache(maxsize=None)
-def _expand_levels_limb_fn(num_levels: int, hash_leaves: bool = False):
+def _expand_levels_limb_fn(num_levels: int, hash_leaves: bool = False,
+                           keep_leaves: bool = False):
     """One jitted program running `num_levels` width-doubling expansion
     levels (the whole `ExpandSeeds` loop fused; widths double per level so
     a scan cannot carry them — the unroll specializes per level count,
     cached across calls). With `hash_leaves` the leaf seeds come back
     already passed through the value MMO hash (single-block value types:
-    fuses `HashExpandedSeeds` into the same program)."""
+    fuses `HashExpandedSeeds` into the same program). With `keep_leaves`
+    the pre-hash leaves come back too, as packed rows (`_Leaves`,
+    natural layout)."""
 
     @jax.jit
     @scoped("dpf.expand")
@@ -429,8 +565,11 @@ def _expand_levels_limb_fn(num_levels: int, hash_leaves: bool = False):
             seeds, control = _expand_level_body(
                 seeds, control, cw_seeds[i], cw_left[i], cw_right[i]
             )
+        kept = _pack_rows(seeds, control) if keep_leaves else None
         if hash_leaves:
             seeds = aes.mmo_hash(fixed_keys.RK_VALUE, seeds)
+        if keep_leaves:
+            return seeds, control, kept
         return seeds, control
 
     return run
@@ -512,7 +651,8 @@ def _expand_levels_planes_fn(num_levels: int, level_kernel: bool = False,
                              tail_kind: str = "concat",
                              head_kind: str = "concat",
                              walk_compact: bool = False,
-                             exit: str = "gather"):
+                             exit: str = "gather",
+                             keep_leaves: bool = False):
     """`_expand_levels_limb_fn` computed in bitsliced plane layout (see
     `pir/dense_eval_planes.py` for the design): children are appended
     [all-left; all-right] per level so the lane order ends up
@@ -521,7 +661,9 @@ def _expand_levels_planes_fn(num_levels: int, level_kernel: bool = False,
     making the output bit-identical to the limb program; with
     `exit="direct"` the leaves leave as `_direct_exit` describes, for
     the lane-dense leaf stage (pure doubling, n0 <= 32 prefixes and
-    `hash_leaves` only). Shared correction words only (one key), like
+    `hash_leaves` only); `keep_leaves` (direct exit only) also returns
+    the pre-hash leaves as packed limb rows (`_Leaves`, planes layout).
+    Shared correction words only (one key), like
     the limb program. With `level_kernel` each level runs the fused
     Pallas VMEM kernel (`ops/expand_planes_pallas.py`); with
     `hash_leaves` the leaf value MMO hash runs before leaving plane
@@ -692,6 +834,12 @@ def _expand_levels_planes_fn(num_levels: int, level_kernel: bool = False,
                     tile_lanes=tile_nodes * (n32 // 32),
                 )
         elif hash_leaves:
+            if keep_leaves:
+                from .ops.aes_bitslice import planes_to_limb_rows
+
+                kept = planes_to_limb_rows(
+                    state.at[0, 0].set(ctrl), min(128, ctrl.shape[0])
+                ).transpose(1, 0, 2, 3)
             if level_kernel:
                 # (same zero-correction reduction as the tail)
                 zeros_vc = jnp.zeros((16, 8, 1), dtype=U32)
@@ -739,7 +887,10 @@ def _expand_levels_planes_fn(num_levels: int, level_kernel: bool = False,
             else:
                 order = tail_node_permutation(order, tail_r, tile_nodes)[0]
         if exit == "direct":
-            return _direct_exit(state, ctrl, order, n0)
+            out = _direct_exit(state, ctrl, order, n0)
+            return out + (kept,) if keep_leaves else out
+        if keep_leaves:
+            raise ValueError("only the direct exit keeps its leaves")
         return _gather_exit(state, ctrl, order, n0)
 
     return run
@@ -806,16 +957,20 @@ def _leaf_exit(p: int, num_levels: int) -> str:
 
 
 def _expand_levels_fn(num_levels: int, hash_leaves: bool = False,
-                      exit: str = "gather"):
+                      exit: str = "gather", keep_leaves: bool = False):
     """Dispatch the fused expansion program (`_planes_plan`): the limb
     program or the plane program with the given `exit`. `hash_leaves`
     fuses the leaf value MMO hash into the same program (single-block
-    value types)."""
+    value types); `keep_leaves` returns the pre-hash leaves as a third
+    output (the limb program, or the plane program's direct exit)."""
     plan = _planes_plan(hash_leaves)
     if plan is None:
         if exit != "gather":
             raise ValueError("the limb program has only the gather exit")
-        return _expand_levels_limb_fn(num_levels, hash_leaves=hash_leaves)
+        return _expand_levels_limb_fn(num_levels, hash_leaves=hash_leaves,
+                                      keep_leaves=keep_leaves)
+    if keep_leaves:
+        plan = dict(plan, keep_leaves=True)
     return _expand_levels_planes_fn(num_levels, hash_leaves=hash_leaves,
                                     exit=exit, **plan)
 
@@ -1081,6 +1236,256 @@ def _leaf_stage_lanes(limbs, ctrl, vc, bits, cepb, party):
     return z.reshape(nlimbs, -1).T
 
 
+@jax.jit
+def _gather_rows(rows, idx):
+    """Rows uint32[m, L] at leaf positions `idx` of a `_Leaves` `rows`
+    array (2-D: natural layout; 4-D: the plane program's limb rows
+    [R, L, 32, T]): one gather of whole rows."""
+    if rows.ndim == 2:
+        return rows[idx]
+    t = rows.shape[-1]
+    word = idx >> 5
+    return rows[word // t, :, idx & 31, word % t]
+
+
+# Chunks the rows exit's kernel program holds at least: one program
+# (one compile of each Pallas kernel) serves every frontier up to 2^20
+# nodes expanded two levels (2^22 leaves, 64 MiB of planes).
+_MIN_CHUNKS = 128
+# Levels of correction words the kernel program takes (zero-padded).
+_CHUNK_LEVELS = 8
+
+
+def _chunk_shape(n_pad: int, num_levels: int):
+    """(T lanes a chunk, live chunks of the frontier, chunks held) of
+    the rows exit's chunk-major planes for `n_pad` nodes (a power of two
+    >= 32) expanded `num_levels` levels."""
+    from .ops.expand_planes_pallas import _TILE_LANES
+
+    g = n_pad // 32
+    t = min(_TILE_LANES, g)
+    live = g // t
+    return t, live, max(_MIN_CHUNKS, _next_pow2(live << num_levels))
+
+
+@functools.partial(jax.jit, static_argnames=("t", "chunks"))
+def _rows_to_chunks(rows, t, chunks):
+    """Packed rows uint32[n, 4] -> chunk-major planes uint32[chunks, 16,
+    8, t] (the control bits out of the seeds, in packed words
+    uint32[chunks, t]), the n / 32 / t live chunks first, zeros after."""
+    from .ops.aes_bitslice import limbs_to_planes
+
+    g = rows.shape[0] // 32
+    state = limbs_to_planes(rows)
+    ctrl = state[0, 0].reshape(g // t, t)
+    state = state.at[0, 0].set(jnp.zeros_like(state[0, 0]))
+    state = state.reshape(16, 8, g // t, t).transpose(2, 0, 1, 3)
+    pad = chunks - g // t
+    return (jnp.pad(state, ((0, pad), (0, 0), (0, 0), (0, 0))),
+            jnp.pad(ctrl, ((0, pad), (0, 0))))
+
+
+@functools.lru_cache(maxsize=None)
+def _chunk_kernels_fn(level_kernel: bool):
+    """One jitted program of the rows exit that holds the expansion
+    kernels, shaped by its buffers alone: expand the `live` leading
+    chunks of chunk-major planes `levels` levels in place, then hash the
+    leaves into a second buffer.
+
+    (state uint32[C, 16, 8, T], ctrl uint32[C, T], live, levels,
+    cwp uint32[L, 16, 8, 1], cwl uint32[L], cwr uint32[L]) ->
+    (pre-hash state, ctrl, hashed state); cwp/cwl/cwr are a level's
+    correction planes and packed direction words (`broadcast_cw_planes`
+    and all-ones/zeros), `live << levels` <= C. A level maps the kernel
+    over the live chunks (a `fori_loop`: one instance of each kernel
+    whatever the width, where the plane program unrolls one call per
+    1024 lanes); chunk i's left children stay at i, its right children
+    go to i + live, the plane program's [all-left; all-right] lane
+    order. Every level count and frontier up to C chunks share one
+    compile."""
+    from .ops.expand_planes_pallas import (
+        expand_level_planes_pallas,
+        value_hash_planes_pallas,
+    )
+    from .ops.aes_bitslice import mmo_hash_planes
+    from .pir.dense_eval_planes import expand_level_planes
+
+    @jax.jit
+    @scoped("dpf.expand")
+    def run(state, ctrl, live, levels, cwp, cwl, cwr):
+        t = state.shape[-1]
+
+        def level(j, carry):
+            state, ctrl, live = carry
+
+            def chunk(i, carry):
+                state, ctrl = carry
+                if level_kernel:
+                    s, c = expand_level_planes_pallas(
+                        state[i], ctrl[i], cwp[j], cwl[j][None],
+                        cwr[j][None],
+                    )
+                else:
+                    s, c = expand_level_planes(state[i], ctrl[i], cwp[j],
+                                               cwl[j], cwr[j])
+                state = state.at[i].set(s[..., :t]).at[i + live].set(
+                    s[..., t:])
+                ctrl = ctrl.at[i].set(c[:t]).at[i + live].set(c[t:])
+                return state, ctrl
+
+            state, ctrl = lax.fori_loop(0, live, chunk, (state, ctrl))
+            return state, ctrl, live * 2
+
+        state, ctrl, live = lax.fori_loop(0, levels, level,
+                                          (state, ctrl, live))
+        zeros_vc = jnp.zeros((16, 8, 1), dtype=U32)
+
+        def value_hash(i, hashed):
+            if level_kernel:
+                h = value_hash_planes_pallas(state[i], ctrl[i], zeros_vc)
+            else:
+                h = mmo_hash_planes(fixed_keys.RK_VALUE, state[i])
+            return hashed.at[i].set(h)
+
+        hashed = lax.fori_loop(0, live, value_hash, jnp.zeros_like(state))
+        return state, ctrl, hashed
+
+    return run
+
+
+def _cw_planes_host(cw_seeds: np.ndarray, cw_left: np.ndarray,
+                    cw_right: np.ndarray, levels: int):
+    """Correction words as the kernel program takes them, built on the
+    host: all-ones/zeros planes uint32[levels, 16, 8, 1] (limb l bit b
+    at byte 4l + b // 8, bit b % 8: `broadcast_cw_planes`) and the
+    direction bits as all-ones/zeros words, zero-padded to `levels`."""
+    n = len(cw_seeds)
+    bits = (cw_seeds[:, :, None] >> np.arange(32, dtype=np.uint32)) & 1
+    cwp = np.zeros((levels, 128), dtype=np.uint32)
+    cwp[:n] = np.uint32(0) - bits.reshape(n, 128).astype(np.uint32)
+    cwl = np.zeros(levels, dtype=np.uint32)
+    cwr = np.zeros(levels, dtype=np.uint32)
+    cwl[:n] = np.uint32(0) - (cw_left & 1)
+    cwr[:n] = np.uint32(0) - (cw_right & 1)
+    return cwp.reshape(levels, 16, 8, 1), cwl, cwr
+
+
+def _chunk_limb_rows(chunks):
+    """The rows exit's limb rows: chunk-major planes
+    uint32[R, 16, 8, T] -> limb rows uint32[R, 4, 32, T], limb l of
+    block 32 * (r * T + t) + i at [r, l, i, t] (`planes_to_limb_rows`
+    with the limbs second). Whole rows are regrouped, none reordered."""
+    from .ops.aes_bitslice import _transpose32_rows
+
+    return _transpose32_rows(chunks.reshape(chunks.shape[0], 4, 32, -1), 2)
+
+
+def _prefix_outputs(blocks, control, vc, party, group, bits, cepb, opp):
+    """The outputs of `IntType(bits)` values at selected leaves:
+    blocks uint32[M, 4] hashed leaf blocks, control uint32[M] their
+    control bits, vc uint32[cepb, nlimbs], party a uint32 scalar. Each
+    block holds `cepb` corrected elements; with `opp` >= `cepb` every
+    element is an output, else the `group`-th run of `opp` of them
+    (group int32[M]). Returns uint32[M * min(opp, cepb), nlimbs]."""
+    nlimbs = max(1, bits // 32)
+    low = U32((1 << bits) - 1) if bits < 32 else None
+    mask = control != 0
+    negate = party != 0
+    elems = []
+    for e in range(cepb):
+        if low is None:
+            v = [blocks[:, e * nlimbs + h] for h in range(nlimbs)]
+        else:
+            v = [(blocks[:, e * bits // 32] >> U32(e * bits % 32)) & low]
+        c = _add_limb_rows(v, [vc[e, h] for h in range(nlimbs)])
+        v = [jnp.where(mask, x, y) for x, y in zip(c, v)]
+        v = [jnp.where(negate, x, y) for x, y in zip(_neg_limb_rows(v), v)]
+        if low is not None:
+            v = [v[0] & low]
+        elems.append(jnp.stack(v, axis=-1))  # [M, nlimbs]
+    out = jnp.stack(elems, axis=1).reshape(-1, nlimbs)
+    if opp < cepb:
+        # The `group`-th run of `opp` elements of each block, read from
+        # the whole blocks' outputs (an interleave of fewer than `cepb`
+        # elements a row compiles for minutes at 2^20 rows on the TPU).
+        o = jnp.arange(out.shape[0] // cepb * opp, dtype=jnp.int32)
+        prefix = o // opp
+        out = out[prefix * cepb + group[prefix] * opp + o % opp]
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _rows_exit_fn(keep_leaves: bool, bits: int, cepb: int, opp: int):
+    """The rows exit after `_chunk_kernels_fn`: the hashed and the
+    pre-hash leaves (their control bits in the seeds' LSBs) leave the
+    planes as limb rows in the plane program's lane order
+    (`_chunk_limb_rows`: no per-leaf gather, no reorder), and one gather
+    of whole 8-word rows reads both at `sel` (one per prefix and block,
+    `_Leaves` positions). Returns the outputs and, with `keep_leaves`,
+    the pre-hash leaves read (packed rows in `sel` order), from which
+    the next level resumes, else None."""
+
+    @jax.jit
+    def run(state, ctrl, hashed, vc, party, sel, group):
+        with jax.named_scope("dpf.expand"):
+            leaves = _chunk_limb_rows(state.at[:, 0, 0].set(ctrl))
+            rows = _gather_rows(
+                jnp.concatenate([_chunk_limb_rows(hashed), leaves], axis=1),
+                sel,
+            )
+            blocks, selected = rows[:, :4], rows[:, 4:]
+        with jax.named_scope("dpf.leaf_values"):
+            out = _prefix_outputs(blocks, selected[:, 0] & U32(1), vc,
+                                  party, group, bits, cepb, opp)
+        return out, (selected if keep_leaves else None)
+
+    return run
+
+
+@functools.lru_cache(maxsize=None)
+def _resume_limb_fn(num_levels: int, keep_leaves: bool, bits: int,
+                    cepb: int, opp: int):
+    """The limb program's resumed level over many nodes: expand packed
+    rows uint32[n, 4] `num_levels` levels, hash the leaves at `sel`
+    (natural `_Leaves` positions) and return their outputs and, with
+    `keep_leaves`, the pre-hash leaves read (as `_rows_exit_fn`)."""
+
+    @jax.jit
+    def run(rows, cw_seeds, cw_left, cw_right, vc, party, sel, group):
+        with jax.named_scope("dpf.expand"):
+            seeds, control = _unpack_rows(rows)
+            for i in range(num_levels):
+                seeds, control = _expand_level_body(
+                    seeds, control, cw_seeds[i], cw_left[i], cw_right[i],
+                )
+            blocks = aes.mmo_hash(fixed_keys.RK_VALUE, seeds[sel])
+        with jax.named_scope("dpf.leaf_values"):
+            out = _prefix_outputs(blocks, control[sel], vc, party, group,
+                                  bits, cepb, opp)
+        if keep_leaves:
+            return out, _pack_rows(seeds[sel], control[sel])
+        return out, None
+
+    return run
+
+
+def _unpack_rows(rows):
+    """Packed rows -> (seeds with the LSB cleared, control bits)."""
+    return rows & jnp.asarray(_CLEAR_LSB), rows[:, 0] & U32(1)
+
+
+@functools.partial(jax.jit, static_argnames=("opp",))
+def _select_prefix_rows(values, rows, opp):
+    """Rows of `opp` consecutive outputs (domain order grouped by tree
+    node) at `rows`, one per prefix: a gather of whole rows."""
+    return jax.tree_util.tree_map(
+        lambda x: x.reshape((-1, opp) + x.shape[1:])[rows].reshape(
+            (-1,) + x.shape[1:]
+        ),
+        values,
+    )
+
+
 @functools.partial(
     jax.jit, static_argnames=("vtype", "num_blocks", "party")
 )
@@ -1101,6 +1506,65 @@ def _leaf_stage_at(seeds, control, vc_dev, block_indices, vtype, num_blocks, par
     if party == 1:
         corrected = vtype.dev_neg(corrected)
     return corrected
+
+
+def _shift_right(a: np.ndarray, bits: int) -> np.ndarray:
+    """`a >> bits` for uint64 or object arrays (a uint64 shift by 64 or
+    more is undefined in numpy: it gives 0 here, as for Python ints)."""
+    if a.dtype == object:
+        return np.array([x >> bits for x in a], dtype=object)
+    if bits >= 64:
+        return np.zeros_like(a)
+    return a >> np.uint64(bits)
+
+
+def _low_bits(a: np.ndarray, bits: int) -> np.ndarray:
+    """`a mod 2^bits` (bits <= 62) of uint64 or object arrays, as int64."""
+    if a.dtype == object:
+        return np.array([x & ((1 << bits) - 1) for x in a], dtype=np.int64)
+    return (a & np.uint64((1 << bits) - 1)).astype(np.int64)
+
+
+def _children(a: np.ndarray, bits: int, c: np.ndarray) -> np.ndarray:
+    """Tree indices `a * 2^bits + c` (c < 2^bits, int64) of uint64 or
+    object arrays."""
+    if a.dtype == object:
+        return np.array([(x << bits) + int(y) for x, y in zip(a, c)],
+                        dtype=object)
+    return (a << np.uint64(bits)) + c.astype(np.uint64)
+
+
+def _read_leaves(rows, keys: np.ndarray) -> _Leaves:
+    """The pre-hash leaves a level's outputs were read from, kept for the
+    next level: `rows` packed uint32[>= len(keys), 4] on the device, the
+    leading ones the leaves of tree indices `keys` (in the caller's
+    order, with repeats where prefixes share a leaf)."""
+    if len(keys) < 2 or bool(np.all(keys[1:] > keys[:-1])):
+        return _Leaves(rows=rows, levels=0, nodes=keys)
+    nodes, first = np.unique(keys, return_index=True)
+    return _Leaves(rows=rows, levels=0, nodes=nodes, row_of=first)
+
+
+def _root_frontier(key: DpfKey) -> PartialEvaluations:
+    """The root's partial evaluation (tree index 0), on the host: its
+    seed keeps its LSB, so it is never packed."""
+    return PartialEvaluations(
+        prefixes=np.zeros(1, dtype=np.uint64),
+        seeds=aes.u128_to_limbs(key.seed)[None, :],
+        control=np.array([key.party], dtype=np.uint32),
+    )
+
+
+def _index_limbs(nodes: np.ndarray, n_pad: int) -> np.ndarray:
+    """Tree indices as uint32[n_pad, 4] limbs (zero rows past the end)."""
+    out = np.zeros((n_pad, 4), dtype=np.uint32)
+    if nodes.dtype == object:
+        for i, t in enumerate(nodes):
+            out[i] = aes.u128_to_limbs(int(t))
+    else:
+        out[: len(nodes), 0] = (nodes & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+        out[: len(nodes), 1] = (nodes >> np.uint64(32)).astype(np.uint32)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -1709,12 +2173,14 @@ class DistributedPointFunction:
 
     def _expand_leaf_values(self, seeds, control, key: DpfKey, start: int,
                             stop: int, hierarchy_level: int,
-                            staged_cw=None):
+                            staged_cw=None, keep: bool = False):
         """Expand seeds from tree level `start` to `stop` and return the
-        leaf values flattened to domain order. Integer values of one
-        block take the direct exit and the lane-dense leaf stage where
-        the expansion plan allows it (`_leaf_exit`); every other value
-        type, and the limb program, take the gather exit and
+        leaf values flattened to domain order, and (with `keep`, where
+        the program allows it: the limb program, or the direct exit)
+        the pre-hash leaves as a `_Leaves`, else None. Integer values of
+        one block take the direct exit and the lane-dense leaf stage
+        where the expansion plan allows it (`_leaf_exit`); every other
+        value type, and the limb program, take the gather exit and
         `_leaf_values`. The `dpf.expand` span names the exit taken."""
         if stop - start > 62:
             raise ValueError(
@@ -1723,39 +2189,68 @@ class DistributedPointFunction:
             )
         vt = self.parameters[hierarchy_level].value_type
         fuse = self._blocks_needed[hierarchy_level] == 1
+        num_levels = stop - start
         exit = "gather"
         if fuse and isinstance(vt, IntType):
-            exit = _leaf_exit(seeds.shape[0], stop - start)
+            exit = _leaf_exit(seeds.shape[0], num_levels)
+        keep = keep and fuse and num_levels > 0 and (
+            exit == "direct" or _planes_plan(fuse) is None
+        )
+        leaves = None
         with tracing.span("dpf.expand", record=False, exit=exit):
+            if staged_cw is None and num_levels > 0:
+                staged_cw = self._device_correction_words(key, start, stop)
             if exit == "gather":
-                seeds, control = self._expand(
-                    seeds, control, key, start, stop, hash_leaves=fuse,
-                    staged_cw=staged_cw,
-                )
-            else:
-                if staged_cw is None:
-                    staged_cw = self._device_correction_words(
-                        key, start, stop
+                if _planes_plan(fuse) is not None:
+                    tracing.runtime_counters.inc(
+                        "dpf.exit.gather_leaves", seeds.shape[0] << num_levels
                     )
-                limbs, ctrl = _expand_levels_fn(
-                    stop - start, hash_leaves=True, exit=exit
+                if keep:
+                    seeds, control, kept = _expand_levels_fn(
+                        num_levels, hash_leaves=True, keep_leaves=True
+                    )(seeds, control, *staged_cw)
+                    leaves = _Leaves(rows=kept, levels=num_levels)
+                else:
+                    seeds, control = self._expand(
+                        seeds, control, key, start, stop, hash_leaves=fuse,
+                        staged_cw=staged_cw,
+                    )
+            else:
+                out = _expand_levels_fn(
+                    num_levels, hash_leaves=True, exit=exit, keep_leaves=keep
                 )(seeds, control, *staged_cw)
+                limbs, ctrl = out[:2]
+                if keep:
+                    limb_levels = _limb_levels(seeds.shape[0], num_levels)
+                    leaves = _Leaves(
+                        rows=out[2], levels=num_levels, layout="planes",
+                        limb_levels=limb_levels,
+                        n32=-(-(seeds.shape[0] << limb_levels) // 32) * 32,
+                    )
         with tracing.span("dpf.leaf_values", record=False):
             if exit == "gather":
                 return self._leaf_values(
                     seeds, control, key, hierarchy_level, pre_hashed=fuse
-                )
+                ), leaves
             cepb = 1 << (
                 self.parameters[hierarchy_level].log_domain_size
                 - self._hierarchy_to_tree[hierarchy_level]
             )
-            vc = np.stack([
-                vt.host_const(v)
-                for v in self._value_correction_host(key, hierarchy_level)
-            ][:cepb])
             return _leaf_stage_lanes(
-                limbs, ctrl, vc, bits=vt.bits, cepb=cepb, party=key.party
-            )
+                limbs, ctrl, self._value_correction_rows(key, hierarchy_level,
+                                                         cepb),
+                bits=vt.bits, cepb=cepb, party=key.party
+            ), leaves
+
+    def _value_correction_rows(self, key: DpfKey, hierarchy_level: int,
+                               cepb: int) -> np.ndarray:
+        """The first `cepb` value corrections of an `IntType` level as
+        limbs uint32[cepb, nlimbs] (one numpy argument)."""
+        vt = self.parameters[hierarchy_level].value_type
+        return np.stack([
+            vt.host_const(v)
+            for v in self._value_correction_host(key, hierarchy_level)
+        ][:cepb])
 
     def evaluate_next(self, prefixes: Sequence[int], ctx: EvaluationContext):
         """Evaluate the hierarchy level after `ctx.previous_hierarchy_level`.
@@ -1770,14 +2265,28 @@ class DistributedPointFunction:
                 ctx.previous_hierarchy_level + 1, prefixes, ctx
             )
 
-    def evaluate_until(self, hierarchy_level: int, prefixes: Sequence[int],
+    def evaluate_until(self, hierarchy_level: int, prefixes,
                        ctx: EvaluationContext):
         """Hierarchical evaluation up to `hierarchy_level` (`EvaluateUntil`).
 
-        Returns a value pytree with leading dim
-        `len(prefixes) * 2^(lds_level - lds_prev)` (or the full expansion on
-        the first call), in domain order grouped by prefix.
+        `prefixes` are domain indices at the previous hierarchy level: a
+        sequence of ints or an integer numpy array (uint64 is the fast
+        path; sorted input maps in O(n)). Returns a value pytree with
+        leading dim `len(prefixes) * 2^(lds_level - lds_prev)` (or the
+        full expansion on the first call), in domain order grouped by
+        prefix, one run per prefix in the caller's order.
+
+        The context keeps the expanded frontier on the device: its
+        partial evaluations (copied to the host only when read or
+        serialized) and the last expansion's pre-hash leaves, from which
+        the next level resumes by position.
         """
+        with tracing.span("dpf.evaluate_until", record=False,
+                          level=hierarchy_level):
+            return self._evaluate_until(hierarchy_level, prefixes, ctx)
+
+    def _evaluate_until(self, hierarchy_level: int, prefixes,
+                        ctx: EvaluationContext):
         key = ctx.key
         self._validate_key(key)
         if hierarchy_level < 0 or hierarchy_level >= len(self.parameters):
@@ -1801,12 +2310,9 @@ class DistributedPointFunction:
                 "output size would exceed 2^62; evaluate fewer hierarchy "
                 "levels at once"
             )
-        for prefix in prefixes:
-            if not (0 <= prefix < (1 << prev_lds)):
-                raise ValueError(f"prefix {prefix} out of range")
-
+        keep = hierarchy_level < len(self.parameters) - 1
         stop_level = self._hierarchy_to_tree[hierarchy_level]
-        if not prefixes:
+        if len(prefixes) == 0:
             # Host spans of a full-domain evaluation (dispatch only: the
             # device runs the programs after these return).
             with tracing.span("dpf.stage_key", record=False):
@@ -1818,116 +2324,225 @@ class DistributedPointFunction:
                     self._device_correction_words(key, 0, stop_level)
                     if stop_level > 0 else None
                 )
-            out = self._expand_leaf_values(
+            out, leaves = self._expand_leaf_values(
                 seeds, control, key, 0, stop_level, hierarchy_level,
-                staged_cw=staged_cw,
+                staged_cw=staged_cw, keep=keep,
             )
+            if leaves is not None:
+                leaves.nodes = np.zeros(1, dtype=np.uint64)
+                self._keep_leaves(ctx, leaves, hierarchy_level)
             ctx.previous_hierarchy_level = hierarchy_level
             return out
 
-        # Split prefixes into unique tree indices + block indices
-        # (packed value types: several prefixes share a tree node).
-        tree_indices: List[int] = []
-        tree_pos: Dict[int, int] = {}
-        prefix_map: List[Tuple[int, int]] = []
-        for prefix in prefixes:
-            ti = self._domain_to_tree_index(prefix, prev_hl)
-            bi = self._domain_to_block_index(prefix, prev_hl)
-            if ti not in tree_pos:
-                tree_pos[ti] = len(tree_indices)
-                tree_indices.append(ti)
-            prefix_map.append((tree_pos[ti], bi))
-
-        update_ctx = hierarchy_level < len(self.parameters) - 1
-        seeds, control = self._compute_partial_evaluations(
-            tree_indices, prev_hl, update_ctx, ctx
-        )
+        vt = self.parameters[hierarchy_level].value_type
         start_level = self._hierarchy_to_tree[prev_hl]
-        values = self._expand_leaf_values(
-            seeds, control, key, start_level, stop_level, hierarchy_level
-        )
-
-        # Select the per-prefix output spans.
-        outputs_per_prefix = 1 << (lds - prev_lds)
+        num_levels = stop_level - start_level
+        opp = 1 << (lds - prev_lds)
         cepb = 1 << (lds - stop_level)
-        blocks_per_tree_prefix = (1 << (stop_level - start_level))
-        span = blocks_per_tree_prefix * cepb
-        idx = np.empty((len(prefixes), outputs_per_prefix), dtype=np.int64)
-        base = np.arange(outputs_per_prefix, dtype=np.int64)
-        for i, (tp, bi) in enumerate(prefix_map):
-            idx[i] = tp * span + bi * outputs_per_prefix + base
-        flat_idx = jnp.asarray(idx.reshape(-1))
-        out = jax.tree_util.tree_map(
-            lambda x: jnp.take(x, flat_idx, axis=0), values
-        )
+        with tracing.span("dpf.prefixes", record=False):
+            nodes, node_of, block = self._plan_prefixes(prefixes, prev_hl)
+            n_pad = _next_pow2(len(nodes))
+            m = len(block)
+            rows_exit = (isinstance(vt, IntType) and n_pad > 32
+                         and self._blocks_needed[hierarchy_level] == 1)
+            if rows_exit:
+                if n_pad << num_levels >= 1 << 31:
+                    raise ValueError(
+                        "too many leaves to expand at once; insert "
+                        "intermediate hierarchy levels"
+                    )
+                # Per prefix, the leaf blocks holding its outputs and
+                # (opp < cepb) which run of `opp` elements of the block.
+                plan = _planes_plan(True)
+                layout = _Leaves(rows=None, levels=num_levels, n32=n_pad,
+                                 layout="natural" if plan is None
+                                 else "planes")
+                first = block * opp
+                blocks_per = max(1, opp // cepb)
+                c, q = first // cepb, node_of
+                if blocks_per > 1:
+                    c = (c[:, None] + np.arange(blocks_per)).ravel()
+                    q = np.repeat(node_of, blocks_per)
+                sel = np.zeros(_next_pow2(m) * blocks_per, dtype=np.int32)
+                sel[: c.size] = layout.position(q, c)
+                group = np.zeros(1, dtype=np.int32)  # read if opp < cepb
+                if opp < cepb:
+                    group = np.zeros(sel.size, dtype=np.int32)
+                    group[:m] = (first % cepb) // opp
+            else:
+                # Rows of `opp` outputs in the expansion's domain order.
+                blocks_per = 1
+                sel = np.zeros(_next_pow2(m), dtype=np.int32)
+                sel[:m] = node_of * (1 << (prev_lds - start_level)) + block
+            if start_level:
+                rows = self._frontier_rows(nodes, node_of, prev_hl, key, ctx,
+                                           n_pad)
+                pe = DevicePartialEvaluations(nodes, rows)
+            else:
+                pe = _root_frontier(key)
+        ctx.partial_evaluations = pe if keep else {}
+        ctx.partial_evaluations_level = prev_hl
+        leaves = None
+        if rows_exit:
+            with tracing.span("dpf.expand", record=False, exit="rows"):
+                cw = self._stage_correction_words(key, start_level,
+                                                  stop_level)
+                tail = (self._value_correction_rows(key, hierarchy_level,
+                                                    cepb),
+                        np.uint32(key.party), sel, group)
+                if plan is None:
+                    out, kept = _resume_limb_fn(
+                        num_levels, keep, vt.bits, cepb, opp
+                    )(rows, *cw, *tail)
+                else:
+                    t, live, chunks = _chunk_shape(n_pad, num_levels)
+                    state, ctrl = _rows_to_chunks(rows, t=t, chunks=chunks)
+                    state, ctrl, hashed = _chunk_kernels_fn(
+                        bool(plan.get("level_kernel"))
+                    )(state, ctrl, np.int32(live), np.int32(num_levels),
+                      *_cw_planes_host(*cw, max(_CHUNK_LEVELS, num_levels)))
+                    out, kept = _rows_exit_fn(
+                        keep, vt.bits, cepb, opp
+                    )(state, ctrl, hashed, *tail)
+                if keep:
+                    leaves = _read_leaves(
+                        kept, _children(nodes[q], num_levels, c)
+                    )
+        else:
+            if start_level:
+                seeds, control = _unpack_rows(rows)
+            else:
+                seeds = jnp.asarray(np.repeat(pe.seeds, n_pad, axis=0))
+                control = jnp.asarray(np.repeat(pe.control, n_pad))
+            values, leaves = self._expand_leaf_values(
+                seeds, control, key, start_level, stop_level,
+                hierarchy_level, keep=keep,
+            )
+            if leaves is not None:
+                leaves.nodes = nodes
+            out = _select_prefix_rows(values, sel, opp=opp)
+        if len(sel) > m * blocks_per:
+            out = jax.tree_util.tree_map(lambda x: x[: m * opp], out)
+        self._keep_leaves(ctx, leaves, hierarchy_level)
         ctx.previous_hierarchy_level = hierarchy_level
+        tracing.runtime_counters.inc("dpf.hier.evaluations")
         return out
 
-    def _compute_partial_evaluations(
-        self, tree_indices: Sequence[int], hierarchy_level: int,
-        update_ctx: bool, ctx: EvaluationContext,
-    ):
-        """Seeds/control bits for `tree_indices` at hierarchy_level's tree
-        level, resuming from `ctx.partial_evaluations` when possible
-        (`ComputePartialEvaluations`, `distributed_point_function.cc:374-476`).
-        """
-        key = ctx.key
-        stop_level = self._hierarchy_to_tree[hierarchy_level]
-        start_level = self._hierarchy_to_tree[ctx.partial_evaluations_level]
-        n = len(tree_indices)
-        # Bucket the batch to the next power of two: distinct prefix counts
-        # would otherwise each compile a fresh XLA program (padding rows
-        # carry zero seeds/paths and are ignored downstream — real rows are
-        # always the leading ones).
-        n_pad = _next_pow2(n)
-        paths_np = np.zeros((n_pad, 4), dtype=np.uint32)
-        paths_np[:n] = np.stack(
-            [aes.u128_to_limbs(t) for t in tree_indices]
-        ).astype(np.uint32)
+    @staticmethod
+    def _keep_leaves(ctx: EvaluationContext, leaves: Optional[_Leaves],
+                     hierarchy_level: int) -> None:
+        """Hand the context a level's pre-hash leaves (or drop the
+        previous ones) for the next level's resume, and account for the
+        device memory its frontier holds (`_hold_frontier`)."""
+        if leaves is not None:
+            leaves.level = hierarchy_level
+            leaves.pe = ctx.partial_evaluations
+        ctx._leaves = leaves
+        _hold_frontier(ctx)
 
-        pe = ctx.partial_evaluations
+    def _plan_prefixes(self, prefixes, prev_hl: int, what: str = "prefix"):
+        """Validate prefixes of hierarchy level `prev_hl` and map them to
+        tree nodes, with array operations only: returns (`nodes`, the
+        sorted unique tree indices, uint64 or object for domains over
+        2^64; `node_of` int32[m], each prefix's position in `nodes`;
+        `block` int64[m], its block index within the node). Sorted
+        input maps in O(n); any order is accepted. An index out of the
+        level's domain raises "`what` <index> out of range"."""
+        lds = self.parameters[prev_hl].log_domain_size
+        bits = lds - self._hierarchy_to_tree[prev_hl]
+        arr = None
+        if lds <= 64:
+            try:
+                arr = np.asarray(prefixes, dtype=np.uint64).reshape(-1)
+            except (OverflowError, TypeError, ValueError):
+                arr = None
+            if (arr is not None and isinstance(prefixes, np.ndarray)
+                    and prefixes.dtype.kind == "i" and (prefixes < 0).any()):
+                arr = None
+        if arr is None:
+            arr = np.empty(len(prefixes), dtype=object)
+            arr[:] = [int(p) for p in prefixes]
+            bad = np.array([not (0 <= p < (1 << lds)) for p in arr])
+        elif lds < 64 and int(arr.max()) >> lds:
+            bad = (arr >> np.uint64(lds)) != 0
+        else:
+            bad = None
+        if bad is not None and bad.any():
+            raise ValueError(f"{what} {int(arr[np.argmax(bad)])} out of range")
+        if arr.dtype == object:
+            tree = np.array([p >> bits for p in arr], dtype=object)
+            block = np.array([p & ((1 << bits) - 1) for p in arr],
+                             dtype=np.int64)
+        else:
+            tree = arr >> np.uint64(bits)
+            block = (arr & np.uint64((1 << bits) - 1)).astype(np.int64)
+        if len(tree) < 2 or bool(np.all(tree[1:] >= tree[:-1])):
+            new = _run_starts(tree)
+            return tree[new], np.cumsum(new, dtype=np.int32) - 1, block
+        nodes, node_of = np.unique(tree, return_inverse=True)
+        return nodes, node_of.astype(np.int32).reshape(-1), block
+
+    def _frontier_rows(self, nodes, node_of, level: int, key: DpfKey,
+                       ctx: Optional[EvaluationContext], n_pad: int):
+        """Packed rows uint32[n_pad, 4] (seed and control bit) of the
+        sorted tree `nodes` at `level`'s tree level (below the root), on
+        the device: gathered by position from the leaves the context
+        keeps when they hold every node (taken as they are when they are
+        exactly `nodes`), else walked from its partial evaluations, or
+        from the root. A node whose parent the context lacks raises,
+        naming the parent of the first such prefix in the caller's
+        order."""
+        stop = self._hierarchy_to_tree[level]
+        n = len(nodes)
+        idx = np.zeros(n_pad, dtype=np.int32)
+        leaves = None if ctx is None else ctx._leaves
+        pe = {} if ctx is None else ctx.partial_evaluations
+        if leaves is not None and leaves.level == level and leaves.pe is pe:
+            if (leaves.levels == 0 and leaves.row_of is None
+                    and leaves.rows.shape[0] == n_pad
+                    and np.array_equal(nodes, leaves.nodes)):
+                return leaves.rows
+            q, found = _lookup(leaves.nodes,
+                               _shift_right(nodes, leaves.levels))
+            if found is None:
+                idx[:n] = leaves.position(q, _low_bits(nodes, leaves.levels))
+                return _gather_rows(leaves.rows, idx)
+
         if isinstance(pe, dict) and pe:
             pe = PartialEvaluations.from_dict(pe)
-        seeds_np = np.zeros((n_pad, 4), dtype=np.uint32)
-        control_np = np.zeros((n_pad,), dtype=np.uint32)
-        if isinstance(pe, PartialEvaluations) and start_level <= stop_level:
-            shift = stop_level - start_level
-            if shift == 0:
-                prev = list(tree_indices)
-            elif shift >= 128:
-                prev = [0] * n
+        start = 0 if ctx is None else (
+            self._hierarchy_to_tree[ctx.partial_evaluations_level]
+        )
+        if len(pe) and start <= stop:
+            parents = _shift_right(nodes, stop - start)
+            q, found = _lookup(pe.prefixes, parents)
+            self._raise_missing(parents, found, node_of, level)
+            idx[:n] = q
+            if isinstance(pe, DevicePartialEvaluations):
+                seeds, control = _unpack_rows(_gather_rows(pe.rows, idx))
             else:
-                prev = [ti >> shift for ti in tree_indices]
-            try:
-                seeds_np[:n], control_np[:n] = pe.lookup(prev)
-            except ValueError as e:
-                raise ValueError(
-                    f"{e} at hierarchy level {hierarchy_level}"
-                ) from None
+                seeds = jnp.asarray(pe.seeds[idx])
+                control = jnp.asarray(pe.control[idx])
         else:
-            seeds_np[:n] = aes.u128_to_limbs(key.seed)
-            control_np[:n] = key.party
-            start_level = 0
-
+            start = 0
+            seeds = jnp.asarray(np.tile(aes.u128_to_limbs(key.seed),
+                                        (n_pad, 1)))
+            control = jnp.full((n_pad,), key.party, dtype=U32)
         seeds, control = self._walk_paths(
-            jnp.asarray(seeds_np),
-            jnp.asarray(control_np),
-            paths_np,
-            key,
-            start_level,
-            stop_level,
+            seeds, control, _index_limbs(nodes, n_pad), key, start, stop,
             rightshift=0,
         )
+        return _pack_rows(seeds, control)
 
-        ctx.partial_evaluations = {}
-        if update_ctx:
-            ctx.partial_evaluations = PartialEvaluations.build(
-                list(tree_indices),
-                np.asarray(seeds)[:n],
-                np.asarray(control)[:n],
-            )
-        ctx.partial_evaluations_level = hierarchy_level
-        return seeds, control
+    @staticmethod
+    def _raise_missing(parents, found, node_of, prev_hl: int) -> None:
+        if found is None:
+            return
+        first = parents[node_of[np.argmax(~found[node_of])]]
+        raise ValueError(
+            f"prefix {int(first)} not present in ctx.partial_evaluations "
+            f"at hierarchy level {prev_hl}"
+        )
 
     def evaluate_at(self, key: DpfKey, hierarchy_level: int,
                     evaluation_points: Sequence[int],
@@ -1936,60 +2551,47 @@ class DistributedPointFunction:
 
         Returns a value pytree with leading dim len(evaluation_points).
         Mirrors `EvaluateAt` (`distributed_point_function.h:913-1070`).
+        With `ctx` the walk resumes from the context's frontier
+        (`_frontier_rows`, as `evaluate_until` does), and the context
+        then holds the points' nodes as its partial evaluations at
+        `hierarchy_level`.
         """
         if ctx is not None and ctx.key is not key:
             raise ValueError("key and ctx.key must be the same object")
         self._validate_key(key)
         if not (0 <= hierarchy_level < len(self.parameters)):
             raise ValueError("hierarchy_level out of range")
-        lds = self.parameters[hierarchy_level].log_domain_size
-        for pt in evaluation_points:
-            if not (0 <= pt < (1 << lds)):
-                raise ValueError(f"evaluation point {pt} out of range")
         n = len(evaluation_points)
         if n == 0:
             vt = self.parameters[hierarchy_level].value_type
             return vt.dev_zeros((0,))
 
-        tree_indices = [
-            self._domain_to_tree_index(pt, hierarchy_level)
-            for pt in evaluation_points
-        ]
-        stop_level = self._hierarchy_to_tree[hierarchy_level]
-        # Bucketed batch (see _compute_partial_evaluations): pad the point
-        # count to a power of two so point-eval shapes recur.
+        nodes, node_of, block = self._plan_prefixes(
+            evaluation_points, hierarchy_level, what="evaluation point"
+        )
+        # Bucketed batch: pad the point count to a power of two so
+        # point-eval shapes recur.
         n_pad = _next_pow2(n)
-
-        if ctx is None:
-            paths_np = np.zeros((n_pad, 4), dtype=np.uint32)
-            paths_np[:n] = np.stack(
-                [aes.u128_to_limbs(t) for t in tree_indices]
-            ).astype(np.uint32)
-            seeds_np = np.zeros((n_pad, 4), dtype=np.uint32)
-            seeds_np[:n] = aes.u128_to_limbs(key.seed)
-            control_np = np.zeros((n_pad,), dtype=np.uint32)
-            control_np[:n] = key.party
-            seeds, control = self._walk_paths(
-                jnp.asarray(seeds_np),
-                jnp.asarray(control_np),
-                paths_np,
-                key,
-                0,
-                stop_level,
-                rightshift=0,
-            )
+        idx = np.zeros((n_pad,), dtype=np.int32)
+        idx[:n] = node_of
+        if self._hierarchy_to_tree[hierarchy_level]:
+            rows = self._frontier_rows(nodes, node_of, hierarchy_level, key,
+                                       ctx, _next_pow2(len(nodes)))
+            pe = DevicePartialEvaluations(nodes, rows)
+            seeds, control = _unpack_rows(_gather_rows(rows, idx))
         else:
-            seeds, control = self._compute_partial_evaluations(
-                tree_indices, hierarchy_level, True, ctx
-            )
+            pe = _root_frontier(key)
+            seeds = jnp.asarray(pe.seeds[idx])
+            control = jnp.asarray(pe.control[idx])
+        if ctx is not None:
+            ctx.partial_evaluations = pe
+            ctx.partial_evaluations_level = hierarchy_level
             ctx.previous_hierarchy_level = hierarchy_level
+            self._keep_leaves(ctx, None, hierarchy_level)
 
         vc_dev = self._stage_value_correction(key, hierarchy_level)
         block_indices_np = np.zeros((n_pad,), dtype=np.int32)
-        block_indices_np[:n] = [
-            self._domain_to_block_index(pt, hierarchy_level)
-            for pt in evaluation_points
-        ]
+        block_indices_np[:n] = block
         vc_dev = jax.tree_util.tree_map(lambda x: x[None], vc_dev)
         out = _leaf_stage_at(
             seeds,

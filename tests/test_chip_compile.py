@@ -181,3 +181,41 @@ def test_full_domain_direct_exit_compiles(one_chip, party):
         if " gather(" in line:
             slices = line.split("slice_sizes={")[1].split("}")[0]
             assert np.prod([int(s) for s in slices.split(",")]) >= 32 * 4
+
+
+def test_hierarchical_rows_exit_compiles(one_chip):
+    """A resumed hierarchy level on the rows exit: the kernel program
+    (chunk-major planes, any level count and frontier up to its chunks)
+    holds one instance of each kernel, and the exit reads the selected
+    leaves with one gather of whole 8-word rows (the hashed and the
+    pre-hash block side by side), returning the outputs
+    in the `{0,1}` layout of the API's uint32[n, 1]. Chunks of 256
+    lanes (a frontier of 2^13 nodes), as the kernel cases above stop
+    there; the cell's 1024-lane chunks compile the same program."""
+    from distributed_point_functions_tpu import dpf as dpf_mod
+
+    nodes = 1 << 13
+    lanes, live, chunks = dpf_mod._chunk_shape(nodes, 2)
+    assert (lanes, live, chunks) == (256, 1, dpf_mod._MIN_CHUNKS)
+
+    def shape(*dims, dtype=U32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    state, ctrl = shape(chunks, 16, 8, lanes), shape(chunks, lanes)
+    levels = dpf_mod._CHUNK_LEVELS
+    kernels = _compile(
+        dpf_mod._chunk_kernels_fn(True), state, ctrl,
+        shape(dtype=jnp.int32), shape(dtype=jnp.int32),
+        shape(levels, 16, 8, 1), shape(levels), shape(levels),
+    ).as_text()
+    assert kernels.count('custom_call_target="tpu_custom_call"') == 2
+
+    sel = shape(nodes, dtype=jnp.int32)
+    text = jax.jit(dpf_mod._rows_exit_fn(True, 32, 4, 4)).lower(
+        state, ctrl, state, shape(4, 1), shape(), sel, sel
+    ).compile().as_text()
+    layout = text.split("entry_computation_layout={")[1].split("\n")[0]
+    assert f"->(u32[{nodes * 4},1]{{0,1:T(1,128)}}" in layout
+    rows = [line for line in text.splitlines()
+            if " gather(" in line and "slice_sizes={1,8,1,1}" in line]
+    assert len(rows) == 1
